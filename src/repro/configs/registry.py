@@ -25,11 +25,15 @@ ARCHS: dict[str, str] = {
 ASSIGNED = [a for a in ARCHS if a not in ("olmo-7b", "llama2-7b")]
 
 
-def get_config(arch: str, smoke: bool = False) -> ModelConfig:
+def get_config(arch: str, smoke: bool = False,
+               layers: int | None = None) -> ModelConfig:
+    """The published config (or its smoke reduction), optionally cut to
+    ``layers`` layers — widths, heads and vocab stay as they are."""
     if arch not in ARCHS:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(ARCHS)}")
     mod = importlib.import_module(f"repro.configs.{ARCHS[arch]}")
-    return mod.SMOKE_CONFIG if smoke else mod.CONFIG
+    cfg = mod.SMOKE_CONFIG if smoke else mod.CONFIG
+    return cfg if layers is None else cfg.replace(n_layers=layers)
 
 
 def iter_cells():

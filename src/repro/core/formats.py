@@ -44,10 +44,17 @@ def cast_fp8(x, fmt: FP8Format):
 
     XLA's convert to e4m3fn produces NaN for out-of-range inputs, so an
     explicit clip implements the saturating semantics hardware quantizers
-    (and the paper) use.
+    (and the paper) use.  The payload leaves through an optimization
+    barrier, so it is materialized in fp8: where the cast and an upcast
+    land in one fusion, XLA:TPU computes the fusion in f32 and skips the
+    rounding (measured on a v5e: the E5M2 gradient compression's
+    error-feedback residual came out 7e-8 of the gradient, not 5e-2).
     """
+    import jax
+
     m = fp8_max(fmt)
-    return jnp.clip(x, -m, m).astype(fp8_dtype(fmt))
+    return jax.lax.optimization_barrier(
+        jnp.clip(x, -m, m).astype(fp8_dtype(fmt)))
 
 
 def e8m0_encode(ratio):

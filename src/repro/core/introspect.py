@@ -18,7 +18,7 @@ from typing import Iterator
 import jax
 import jax.numpy as jnp
 
-from repro.compat.jaxapi import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def iter_eqns(jaxpr, skip_into: tuple[str, ...] = ()) -> Iterator:
@@ -287,3 +287,9 @@ def weight_slice_sizes(cfg) -> set[int]:
 
     jax.tree.map(add, defs, sdims, is_leaf=is_pdef)
     return sizes
+
+
+def count_pallas_calls(compiled) -> int:
+    """Pallas TPU kernel launches in a compiled executable's HLO — zero
+    on CPU, where kernels run as the jnp reference or interpreted."""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat.jaxapi import shard_map
 from repro.core.quant import quant_per_tensor
 
 
@@ -68,7 +67,7 @@ def fp8_allreduce_grads(grads, residuals, mesh, dp_axes=("pod", "data"),
         return red.astype(g_loc.dtype), new_r
 
     def one(g, r):
-        return shard_map(body, mesh=mesh, in_specs=(P(), P()),
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
                          out_specs=(P(), P()), check_vma=False)(g, r)
 
     g_leaves, treedef = jax.tree.flatten(grads)

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat.jaxapi import shard_map
-
 
 def pipeline_forward(mesh, stage_fn, params_stacked, x_micro,
                      *, n_stages: int):
@@ -75,7 +73,7 @@ def pipeline_forward(mesh, stage_fn, params_stacked, x_micro,
         return jax.lax.psum(outs, "pod")
 
     spec_p = jax.tree.map(lambda _: P("pod"), params_stacked)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_p, P()), out_specs=P(),
         check_vma=False,

@@ -14,7 +14,6 @@ import threading
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat.jaxapi import abstract_mesh
 
 # logical name -> tuple of candidate mesh axes (joined as a tuple spec
 # entry).  "batch" spans pod+data so the pod axis is pure DP.
@@ -71,11 +70,12 @@ def use_mesh(mesh: Mesh):
         _state.mesh = old
 
 
-def _active_mesh() -> Mesh | None:
+def active_mesh() -> Mesh | None:
     mesh = getattr(_state, "mesh", None)
     if mesh is not None:
         return mesh
-    return abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()       # a jax.set_mesh scope
+    return None if am.empty else am
 
 
 def resolve_spec(logical: tuple[str | None, ...],
@@ -114,7 +114,7 @@ def resolve_spec(logical: tuple[str | None, ...],
 
 def shard(x: jax.Array, *logical: str | None) -> jax.Array:
     """with_sharding_constraint by logical names; no-op outside a mesh."""
-    mesh = _active_mesh()
+    mesh = active_mesh()
     if mesh is None:
         return x
     spec = resolve_spec(tuple(logical), mesh, tuple(x.shape))

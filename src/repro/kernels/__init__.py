@@ -9,6 +9,8 @@
 #   moe_gmm.py     grouped-expert ragged GEMM (MoE): fused quantize +
 #                  all expert GEMMs in one launch + the grouped dW
 #   mx_quant.py    standalone fused two-level quantizer
+#   mx_tile.py     in-kernel micro-group math shared by the MX kernels
+#                  (micro-groups on sublanes) + their exponent layout
 #   decode_attn.py fused decode attention over the fp8/bf16 KV cache
 #                  (scale application + ring masking + softmax +
 #                  combine in one launch — the serving hot path)

@@ -14,14 +14,16 @@ call the backend is chosen by ``repro.core.runtime_flags.kernel_backend``:
               the CPU execution default (XLA fuses it)
 
 The kernel paths impose TPU-friendly alignment (M/N blocks of 128, K
-micro-group multiples); this module zero-pads operands up to block
+micro-group multiples, and multiples of 256 past 512 — ``_k_pad``);
+this module zero-pads operands up to block
 multiples and slices results back, so callers see one shape contract
 across backends.  Zero padding is exact under every quantizer here
 (amax of an all-zero group clamps to TINY → q = 0 → contributes 0).
 
-Kernels hardcode the paper's micro-group of 32 and COAT group of 128;
-non-default geometries silently take the reference path (they exist
-only for ablations).
+Kernels hardcode the paper's micro-group of 32 and COAT group of 128.
+Non-default geometries exist only for ablations and must ask for
+``backend="ref"``: a kernel backend that cannot take a call raises
+instead of quietly running the reference.
 
 Weight operands always arrive here as fp8 payload + f32 scale
 (``PerTensorQ``) — whether quantized in-graph by ``core.linear``
@@ -71,11 +73,81 @@ def _pad_to(x: jax.Array, axis: int, target: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def _kernel_only(backend: str, ok: bool, what: str) -> None:
+    """Kernel backends take only the kernel geometry — no silent
+    reference fallback under ``pallas``/``interpret``."""
+    if not ok:
+        raise ValueError(
+            f"backend={backend!r} has no kernel for {what}; ask for "
+            f"backend='ref' explicitly")
+
+
+def _k_pad(k: int) -> int:
+    """K padded for the MX kernels: a micro-group multiple, and past
+    one 512-wide block a multiple of 256, so every K block holds
+    bk/32 ≥ 8 exponent sublanes (kernels/mx_tile.py)."""
+    return _ceil_to(k, MICRO) if k <= 512 else _ceil_to(k, 256)
+
+
 def _k_block(kp: int) -> int:
-    for b in (512, 256, 128, 64, 32):
+    """K block for a ``_k_pad``-ed K: 512 or 256, else all of K (a
+    block equal to the full dim is always legal)."""
+    for b in (512, 256):
         if kp % b == 0:
             return b
-    raise AssertionError(f"K={kp} not a multiple of {MICRO}")
+    assert kp % MICRO == 0, f"K={kp} not a multiple of {MICRO}"
+    return kp
+
+
+def _per_shard(fn, *args, rows: tuple[int, ...],
+               summed_rows: int | None = None):
+    """``fn(*args)`` once per batch shard when the caller is partitioned
+    over a mesh (GSPMD cannot partition a Pallas call, so the kernel
+    runs inside ``shard_map``): the operands at positions ``rows`` are
+    split along their leading token dim over the mesh's batch axes, the
+    rest replicated (weights are all-gathered).  Outputs are row-split
+    the same way; or, given ``summed_rows`` (the output's leading dim),
+    summed over those axes — the dW contraction over tokens — and left
+    scattered along that dim where it divides, as a data-parallel
+    gradient is.  A plain call without such a mesh, and inside a
+    ``shard_map`` (already per device).
+
+    Only data parallelism: a mesh axis > 1 the token rows are not split
+    over (tensor parallelism over ``model``) raises rather than run
+    every kernel on the full weight on each of its devices."""
+    import math
+
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import active_mesh, resolve_spec
+
+    mesh = active_mesh()
+    if (mesh is None or jax.sharding.get_abstract_mesh().manual_axes
+            or mesh.size == 1):
+        return fn(*args)
+    row = resolve_spec(("batch",), mesh, (args[rows[0]].shape[0],))
+    axes = row[0] if len(row) else None
+    split = (axes,) if isinstance(axes, str) else tuple(axes or ())
+    dropped = {ax: n for ax, n in mesh.shape.items()
+               if ax not in split and n > 1}
+    if dropped:
+        raise NotImplementedError(
+            f"the MOSS kernels run per batch shard only; mesh axes "
+            f"{dropped} do not split the {args[rows[0]].shape[0]} token "
+            f"rows — use backend='ref' on this mesh")
+    in_specs = tuple(row if i in rows else P() for i in range(len(args)))
+    if summed_rows is None:
+        local, out_spec = fn, row
+    elif summed_rows % math.prod(mesh.shape[ax] for ax in split) == 0:
+        def local(*a):
+            return jax.lax.psum_scatter(fn(*a), split, scatter_dimension=0,
+                                        tiled=True)
+        out_spec = P(axes)
+    else:
+        def local(*a):
+            return jax.lax.psum(fn(*a), split)
+        out_spec = P()
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)(*args)
 
 
 def _m_block(mp: int, min_mult: int = 8) -> int:
@@ -97,15 +169,17 @@ def mx_quantize(x2d: jax.Array, fmt: str = "e4m3",
     backend = _resolve(backend)
     assert x2d.shape[-1] % micro_group == 0, \
         f"K={x2d.shape[-1]} not divisible by micro_group={micro_group}"
-    if backend == "ref" or micro_group != MICRO:
+    if backend == "ref":
         return Q.quant_mx(x2d, micro_group, fmt)
+    _kernel_only(backend, micro_group == MICRO,
+                 f"micro_group={micro_group}")
     m, k = x2d.shape
     s = ref.global_scale_ref(x2d, fmt)
-    mp = _ceil_to(m, 8)
-    q, e = mx_quant_pallas(_pad_to(x2d, 0, mp), s, fmt=fmt,
-                           bm=_m_block(mp), bk=_k_block(k),
+    mp, kp = _ceil_to(m, 8), _k_pad(k)
+    q, e = mx_quant_pallas(_pad_to(_pad_to(x2d, 0, mp), 1, kp), s,
+                           fmt=fmt, bm=_m_block(mp), bk=_k_block(kp),
                            interpret=backend == "interpret")
-    return MxQ(q=q[:m], sexp=e[:m], s=s)
+    return MxQ(q=q[:m, :k], sexp=e[:m, :k // MICRO], s=s)
 
 
 def mx_matmul(xq: MxQ, wq: PerTensorQ, out_dtype=jnp.bfloat16,
@@ -114,11 +188,13 @@ def mx_matmul(xq: MxQ, wq: PerTensorQ, out_dtype=jnp.bfloat16,
     level-2 rescale rides the operand, one f32 epilogue multiply."""
     backend = _resolve(backend)
     micro = xq.q.shape[-1] // xq.sexp.shape[-1]
-    if backend == "ref" or micro != MICRO or xq.q.ndim != 2:
+    if backend == "ref":
         return Q.mx_gemm(xq, wq, out_dtype=out_dtype)
+    _kernel_only(backend, micro == MICRO and xq.q.ndim == 2,
+                 f"micro_group={micro}, {xq.q.ndim}-D operand")
     m, k = xq.q.shape
     n = wq.q.shape[-1]
-    mp, np_, kp = _ceil_to(m, 128), _ceil_to(n, 128), _ceil_to(k, MICRO)
+    mp, np_, kp = _ceil_to(m, 128), _ceil_to(n, 128), _k_pad(k)
     acc = mx_gemm_pallas(
         _pad_to(_pad_to(xq.q, 0, mp), 1, kp),
         _pad_to(_pad_to(xq.sexp, 0, mp), 1, kp // MICRO),
@@ -142,20 +218,27 @@ def fused_quant_matmul(x2d: jax.Array, wq: PerTensorQ,
     # boundaries must tile K exactly (callers pad — see linear._pad_axis)
     assert x2d.shape[-1] % micro_group == 0, \
         f"K={x2d.shape[-1]} not divisible by micro_group={micro_group}"
-    if backend == "ref" or micro_group != MICRO:
+    if backend == "ref":
         xq = Q.quant_mx(x2d, micro_group, fmt)
         return Q.mx_gemm(xq, wq, out_dtype=out_dtype), xq
-    m, k = x2d.shape
-    n = wq.q.shape[-1]
-    s = ref.global_scale_ref(x2d, fmt)
-    mp, np_, kp = _ceil_to(m, 128), _ceil_to(n, 128), _ceil_to(k, MICRO)
-    acc, q, sexp = fused_quant_gemm_pallas(
-        _pad_to(_pad_to(x2d, 0, mp), 1, kp), s,
-        _pad_to(_pad_to(wq.q, 0, kp), 1, np_),
-        fmt=fmt, bm=128, bn=128, bk=_k_block(kp),
-        interpret=backend == "interpret")
-    y = (acc[:m, :n] * (s * wq.s)).astype(out_dtype)
-    return y, MxQ(q=q[:m, :k], sexp=sexp[:m, :k // MICRO], s=s)
+    _kernel_only(backend, micro_group == MICRO,
+                 f"micro_group={micro_group}")
+    s = ref.global_scale_ref(x2d, fmt)      # level 1: over all shards
+
+    def local(x2d, qw, s, s_w):
+        m, k = x2d.shape
+        n = qw.shape[-1]
+        mp, np_, kp = _ceil_to(m, 128), _ceil_to(n, 128), _k_pad(k)
+        acc, q, sexp = fused_quant_gemm_pallas(
+            _pad_to(_pad_to(x2d, 0, mp), 1, kp), s,
+            _pad_to(_pad_to(qw, 0, kp), 1, np_),
+            fmt=fmt, bm=128, bn=128, bk=_k_block(kp),
+            interpret=backend == "interpret")
+        return ((acc[:m, :n] * (s * s_w)).astype(out_dtype), q[:m, :k],
+                sexp[:m, :k // MICRO])
+
+    y, q, sexp = _per_shard(local, x2d, wq.q, s, wq.s, rows=(0,))
+    return y, MxQ(q=q, sexp=sexp, s=s)
 
 
 def mx_matmul_dw(xq: MxQ, gq: PerTensorQ, fmt: str = "e4m3",
@@ -174,7 +257,8 @@ def mx_matmul_dw(xq: MxQ, gq: PerTensorQ, fmt: str = "e4m3",
     micro = xq.q.shape[-1] // xq.sexp.shape[-1]
     m, k = xq.q.shape
     n = gq.q.shape[-1]
-    if backend == "ref" or micro != MICRO:
+    rows_out = k if out_rows is None else out_rows
+    if backend == "ref":
         mp = _ceil_to(m, micro)
         x_unit = MxQ(_pad_to(xq.q, 0, mp), _pad_to(xq.sexp, 0, mp),
                      jnp.float32(1.0)).dequant(jnp.float32)  # Qx·2^sexp
@@ -182,16 +266,23 @@ def mx_matmul_dw(xq: MxQ, gq: PerTensorQ, fmt: str = "e4m3",
                         global_scale=jnp.float32(1.0))
         acc = Q.mx_gemm(xt, PerTensorQ(q=_pad_to(gq.q, 0, mp),
                                        s=jnp.float32(1.0)),
-                        out_dtype=jnp.float32)
+                        out_dtype=jnp.float32)[:rows_out, :n]
     else:
-        mp, np_, kp = _ceil_to(m, 128), _ceil_to(n, 128), _ceil_to(k, MICRO)
-        acc = mx_dw_gemm_pallas(
-            _pad_to(_pad_to(xq.q, 0, mp), 1, kp),
-            _pad_to(_pad_to(xq.sexp, 0, mp), 1, kp // MICRO),
-            _pad_to(_pad_to(gq.q, 0, mp), 1, np_),
-            fmt=fmt, bm=128, bn=128, bko=_k_block(kp),
-            interpret=backend == "interpret")
-    acc = acc[:k if out_rows is None else out_rows, :n]
+        _kernel_only(backend, micro == MICRO, f"micro_group={micro}")
+
+        def local(qx, sexp, qg):
+            mp = _ceil_to(qx.shape[0], 128)
+            np_, kp = _ceil_to(n, 128), _k_pad(k)
+            acc = mx_dw_gemm_pallas(
+                _pad_to(_pad_to(qx, 0, mp), 1, kp),
+                _pad_to(_pad_to(sexp, 0, mp), 1, kp // MICRO),
+                _pad_to(_pad_to(qg, 0, mp), 1, np_),
+                fmt=fmt, bm=128, bn=128, bko=_k_block(kp),
+                interpret=backend == "interpret")
+            return acc[:rows_out, :n]
+
+        acc = _per_shard(local, xq.q, xq.sexp, gq.q, rows=(0, 1, 2),
+                         summed_rows=rows_out)
     return (acc * (xq.s * gq.s)).astype(out_dtype)
 
 
@@ -222,18 +313,21 @@ def moe_grouped_matmul(x2d: jax.Array, group_sizes: jax.Array,
     assert k % micro_group == 0, \
         f"K={k} not divisible by micro_group={micro_group}"
     s = ref.global_scale_ref(x2d, fmt)
-    if backend == "ref" or micro_group != MICRO:
+    if backend == "ref":
         xq = Q.quant_mx(x2d, micro_group, fmt, global_scale=s)
         acc = ref.moe_gmm_ref(xq.q, xq.sexp, qw_stack, capacity)
     else:
-        np_ = _ceil_to(n, 128)
+        _kernel_only(backend, micro_group == MICRO,
+                     f"micro_group={micro_group}")
+        np_, kp = _ceil_to(n, 128), _k_pad(k)
         acc, q, sexp = moe_gmm_pallas(
-            x2d, s, _pad_to(qw_stack, 2, np_),
+            _pad_to(x2d, 1, kp), s,
+            _pad_to(_pad_to(qw_stack, 1, kp), 2, np_),
             group_sizes.astype(jnp.int32), capacity=capacity, fmt=fmt,
-            bm=_m_block(capacity), bn=128, bk=_k_block(k),
+            bm=_m_block(capacity), bn=128, bk=_k_block(kp),
             interpret=backend == "interpret")
         acc = acc[:, :n]
-        xq = MxQ(q=q, sexp=sexp, s=s)
+        xq = MxQ(q=q[:, :k], sexp=sexp[:, :k // MICRO], s=s)
     row_scale = s * jnp.repeat(w_scales.astype(jnp.float32), capacity)
     y = (acc * row_scale[:, None]).astype(out_dtype)
     return y, xq
@@ -256,7 +350,9 @@ def moe_grouped_matmul_dw(xq: MxQ, gq: PerTensorQ,
     e = t // capacity
     n = gq.q.shape[-1]
     micro = xq.q.shape[-1] // xq.sexp.shape[-1]
-    use_ref = backend == "ref" or micro != MICRO
+    use_ref = backend == "ref"
+    if not use_ref:
+        _kernel_only(backend, micro == MICRO, f"micro_group={micro}")
     # per-expert rows padded so the along-token requant groups (micro
     # tokens each) never straddle an expert boundary
     cp = _ceil_to(capacity, micro if use_ref else MICRO)
@@ -271,12 +367,13 @@ def moe_grouped_matmul_dw(xq: MxQ, gq: PerTensorQ,
     if use_ref:
         acc = ref.moe_dw_ref(qx, sexp, qg, cp, fmt, micro)
     else:
-        np_ = _ceil_to(n, 128)
+        np_, kp = _ceil_to(n, 128), _k_pad(k)
         acc = moe_dw_gemm_pallas(
-            qx, sexp, _pad_to(qg, 1, np_),
+            _pad_to(qx, 1, kp), _pad_to(sexp, 1, kp // MICRO),
+            _pad_to(qg, 1, np_),
             group_sizes.astype(jnp.int32), capacity=cp, fmt=fmt,
             bm=_m_block(cp, min_mult=MICRO), bn=128,
-            bko=_k_block(k), interpret=backend == "interpret")
+            bko=_k_block(kp), interpret=backend == "interpret")
     acc = acc[:, :k if out_rows is None else out_rows, :n]
     return (acc * (xq.s * gq.s)).astype(out_dtype)
 
@@ -405,8 +502,10 @@ def group_matmul(xq: PerGroupQ, wq: PerTensorQ, out_dtype=jnp.bfloat16,
     partial sum inside the K loop — the overhead MOSS removes."""
     backend = _resolve(backend)
     group = xq.q.shape[-1] // xq.s.shape[-1]
-    if backend == "ref" or group != GROUP or xq.q.ndim != 2:
+    if backend == "ref":
         return Q.group_gemm(xq, wq, out_dtype=out_dtype)
+    _kernel_only(backend, group == GROUP and xq.q.ndim == 2,
+                 f"group={group}, {xq.q.ndim}-D operand")
     m, k = xq.q.shape
     n = wq.q.shape[-1]
     mp, np_ = _ceil_to(m, 128), _ceil_to(n, 128)

@@ -23,7 +23,7 @@ and ONE level-1 amax over the whole token buffer:
 ``moe_dw_gemm_pallas``
     The grouped dW backward: for every expert, ``requant_M(x̂_e)ᵀ @ Qg_e``
     over that expert's row range — the ``mx_bwd.py`` fusion
-    (dequant → transpose → requant along tokens, level-1 scale pinned to
+    (dequant → requant along tokens, level-1 scale pinned to
     s_x so it cancels in-kernel) with an extra expert grid dimension
     writing the stacked ``(E, K, N)`` weight gradient in one launch.
 
@@ -44,7 +44,8 @@ Operand contract (see docs/kernel-contract.md)
                                     caller (row-wise epilogue)
   group_sizes (E,)       int32    — scalar-prefetch (SMEM) operand
   returns acc (E·C, N) f32 UNSCALED, q (E·C, K) fp8,
-          sexp (E·C, K//32) int8
+          sexp (E·C, K//32) int8 (written in the (E·C/bm, K/32, bm)
+          tile layout of kernels/mx_tile.py; the wrapper converts)
 ``moe_dw_gemm_pallas``:
   qx (E·C, K) fp8 + sexp (E·C, K//32) int8 — grouped forward residual
   qg (E·C, N) fp8 — gradient, ONE per-tensor scale for the buffer
@@ -69,11 +70,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.jaxapi import pallas_tpu_compiler_params
 from repro.core.formats import E4M3_MAX, E5M2_MAX
 
-MICRO = 32
-_TINY = 1e-30
+from .mx_tile import (MICRO, TILE_DTYPE, dequant_tile, dot_t, quant_tile,
+                      requant_rows, scaled_operand_t, sexp_from_tiles,
+                      sexp_to_tiles, untranspose)
 
 
 # ---------------------------------------------------------------------------
@@ -95,28 +96,15 @@ def _moe_gmm_kernel(sz_ref, x_ref, s_ref, qw_ref, o_ref, q_ref, se_ref,
     # quantize unconditionally: the residual must cover every row (zero
     # rows quantize to q=0 / sexp=-127, bit-identical to the reference)
     x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
-    bm_, bk = x.shape
-    s = jnp.maximum(s_ref[0, 0], _TINY)
-    xg = x.reshape(bm_, bk // MICRO, MICRO)
-    amax = jnp.max(jnp.abs(xg), axis=-1)                  # (bm, bk/32)
-    ee = jnp.ceil(jnp.log2(jnp.maximum(amax / fp8_max / s,
-                                       2.0 ** -149)) - 1e-6)
-    ee = jnp.clip(ee, -127, 127)
-    se_ref[...] = ee.astype(jnp.int8)
-    denom = jnp.exp2(ee) * s
-    safe = jnp.where(denom > 0, denom, 1.0)[..., None]
-    q = jnp.where(denom[..., None] > 0, xg / safe, 0.0)
-    q = jnp.clip(q, -fp8_max, fp8_max).astype(q_dtype)    # saturating cast
-    q_ref[...] = q.reshape(bm_, bk)
+    ee, q = quant_tile(x, s_ref[0, 0], fp8_max=fp8_max, q_dtype=q_dtype)
+    se_ref[0] = ee.astype(TILE_DTYPE)                     # (bk/32, bm)
+    q_ref[...] = untranspose(q)
 
     # grouped MXU dot — skipped for row blocks past the group's count
     @pl.when((i * bm) % cap < sz_ref[e])
     def _dot():
-        ss = jnp.exp2(ee).astype(jnp.bfloat16)
-        xop = (q.astype(jnp.bfloat16) * ss[..., None]).reshape(bm_, bk)
         w = qw_ref[0].astype(jnp.bfloat16)                # (bk, bn)
-        acc_ref[...] += jnp.dot(xop, w,
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += dot_t(scaled_operand_t(q, ee), w)
 
     @pl.when(kk == n_k - 1)
     def _done():
@@ -161,7 +149,8 @@ def moe_gmm_pallas(x, s_global, qw_stack, group_sizes, *, capacity: int,
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk, sz: (i, j)),
             pl.BlockSpec((bm, bk), lambda i, j, kk, sz: (i, kk)),
-            pl.BlockSpec((bm, bk // MICRO), lambda i, j, kk, sz: (i, kk)),
+            pl.BlockSpec((1, bk // MICRO, bm),
+                         lambda i, j, kk, sz: (i, kk, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
@@ -172,13 +161,13 @@ def moe_gmm_pallas(x, s_global, qw_stack, group_sizes, *, capacity: int,
         out_shape=[
             jax.ShapeDtypeStruct((t, n), jnp.float32),
             jax.ShapeDtypeStruct((t, k), q_dtype),
-            jax.ShapeDtypeStruct((t, k // MICRO), jnp.int8),
+            jax.ShapeDtypeStruct((t // bm, k // MICRO, bm), TILE_DTYPE),
         ],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(group_sizes, x, s_global.reshape(1, 1), qw_stack)
-    return acc, q, sexp
+    return acc, q, sexp_from_tiles(sexp)
 
 
 # ---------------------------------------------------------------------------
@@ -197,29 +186,13 @@ def _moe_dw_kernel(sz_ref, qx_ref, se_ref, qg_ref, o_ref, acc_ref, *,
 
     @pl.when(mi * bm < sz_ref[ei])
     def _dot():
-        x = qx_ref[...].astype(jnp.float32)               # (bm, bko)
-        bm_, bko = x.shape
-        # dequant by the forward's level-2 exponents (units of s_x)
-        ss_fwd = jnp.exp2(se_ref[...].astype(jnp.float32))
-        xd = (x.reshape(bm_, bko // MICRO, MICRO) * ss_fwd[..., None]
-              ).reshape(bm_, bko)
-        xt = xd.T                                         # (bko, bm)
+        # dequant by the forward's level-2 exponents (units of s_x),
         # requant along M (tokens of THIS expert's row range); level-1
         # scale pinned to s_x, which cancels — see kernels/mx_bwd.py
-        xg = xt.reshape(bko, bm_ // MICRO, MICRO)
-        amax = jnp.max(jnp.abs(xg), axis=-1)
-        ee = jnp.ceil(jnp.log2(jnp.maximum(amax / fp8_max,
-                                           2.0 ** -149)) - 1e-6)
-        ee = jnp.clip(ee, -127, 127)
-        ss = jnp.exp2(ee)
-        safe = jnp.where(ss > 0, ss, 1.0)[..., None]
-        q = jnp.where(ss[..., None] > 0, xg / safe, 0.0)
-        q = jnp.clip(q, -fp8_max, fp8_max).astype(q_dtype)
-        xop = (q.astype(jnp.bfloat16)
-               * ss.astype(jnp.bfloat16)[..., None]).reshape(bko, bm_)
+        xd = dequant_tile(qx_ref[...], se_ref[0])         # (bm, bko)
+        x_op = requant_rows(xd, fp8_max=fp8_max, q_dtype=q_dtype)
         g = qg_ref[...].astype(jnp.bfloat16)              # (bm, bn)
-        acc_ref[...] += jnp.dot(xop, g,
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += dot_t(x_op, g)
 
     @pl.when(mi == n_m - 1)
     def _done():
@@ -259,8 +232,8 @@ def moe_dw_gemm_pallas(qx, sexp, qg, group_sizes, *, capacity: int,
         in_specs=[
             pl.BlockSpec((bm, bko),
                          lambda ei, ki, ni, mi, sz: (ei * n_m + mi, ki)),
-            pl.BlockSpec((bm, bko // MICRO),
-                         lambda ei, ki, ni, mi, sz: (ei * n_m + mi, ki)),
+            pl.BlockSpec((1, bko // MICRO, bm),
+                         lambda ei, ki, ni, mi, sz: (ei * n_m + mi, ki, 0)),
             pl.BlockSpec((bm, bn),
                          lambda ei, ki, ni, mi, sz: (ei * n_m + mi, ni)),
         ],
@@ -274,7 +247,7 @@ def moe_dw_gemm_pallas(qx, sexp, qg, group_sizes, *, capacity: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, k, n), jnp.float32),
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(group_sizes, qx, sexp, qg)
+    )(group_sizes, qx, sexp_to_tiles(sexp, bm), qg)

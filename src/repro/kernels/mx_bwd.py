@@ -22,10 +22,12 @@ is ≤ 1 and the E8M0 ceil guarantee still holds — same trade COAT makes
 with its transposed quantized copy, minus the extra memory pass.)
 
 Grid (K/bko, N/bn, M/bm), M (the contraction) innermost "arbitrary";
-per M-block the kernel dequants Qx·2^sexp, transposes in-VMEM, requants
-along M, rescales the operand by 2^e', and accumulates the MXU dot with
-the E5M2 gradient tile.  Epilogue (× s_x·s_g) happens in the dispatch
-layer.
+per M-block the kernel dequants Qx·2^sexp, requants along M (32-row
+micro-groups on the sublane axis — kernels/mx_tile.py), rescales the
+operand by 2^e', and accumulates the MXU dot (contracting M) with the
+E5M2 gradient tile.  The forward exponents arrive in the
+(M/bm, K/32, bm) tile layout of kernels/mx_tile.py.  Epilogue
+(× s_x·s_g) happens in the dispatch layer.
 
 Operand contract (see docs/kernel-contract.md)
 ----------------------------------------------
@@ -56,10 +58,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.jaxapi import pallas_tpu_compiler_params
 from repro.core.formats import E4M3_MAX, E5M2_MAX
 
-MICRO = 32
+from .mx_tile import MICRO, dequant_tile, dot_t, requant_rows, sexp_to_tiles
 
 
 def _mx_dw_gemm_kernel(qx_ref, se_ref, qg_ref, o_ref, acc_ref, *,
@@ -70,29 +71,13 @@ def _mx_dw_gemm_kernel(qx_ref, se_ref, qg_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = qx_ref[...].astype(jnp.float32)                   # (bm, bko)
-    bm, bko = x.shape
-    # dequant by the forward's level-2 exponents (units of s_x)
-    ss_fwd = jnp.exp2(se_ref[...].astype(jnp.float32))    # (bm, bko/32)
-    xd = (x.reshape(bm, bko // MICRO, MICRO) * ss_fwd[..., None]
-          ).reshape(bm, bko)
-    xt = xd.T                                             # (bko, bm)
-    # requant along M: micro-groups of 32 tokens, level-1 scale = s_x
-    # (which cancels — see module docstring)
-    xg = xt.reshape(bko, bm // MICRO, MICRO)
-    amax = jnp.max(jnp.abs(xg), axis=-1)                  # (bko, bm/32)
-    e = jnp.ceil(jnp.log2(jnp.maximum(amax / fp8_max,
-                                      2.0 ** -149)) - 1e-6)
-    e = jnp.clip(e, -127, 127)
-    ss = jnp.exp2(e)
-    safe = jnp.where(ss > 0, ss, 1.0)[..., None]
-    q = jnp.where(ss[..., None] > 0, xg / safe, 0.0)
-    q = jnp.clip(q, -fp8_max, fp8_max).astype(q_dtype)    # fp8 requant
-    # operand: requantized values × 2^e (exact po2 rescale in bf16)
-    xop = (q.astype(jnp.bfloat16) * ss.astype(jnp.bfloat16)[..., None]
-           ).reshape(bko, bm)
+    # dequant by the forward's level-2 exponents (units of s_x), then
+    # requant along M with level-1 scale = s_x (which cancels — see
+    # module docstring)
+    xd = dequant_tile(qx_ref[...], se_ref[0])             # (bm, bko)
+    x_op = requant_rows(xd, fp8_max=fp8_max, q_dtype=q_dtype)
     g = qg_ref[...].astype(jnp.bfloat16)                  # (bm, bn)
-    acc_ref[...] += jnp.dot(xop, g, preferred_element_type=jnp.float32)
+    acc_ref[...] += dot_t(x_op, g)                        # (bko, bn)
 
     @pl.when(mi == n_m - 1)
     def _done():
@@ -127,13 +112,14 @@ def mx_dw_gemm_pallas(qx, sexp, qg, *, fmt: str = "e4m3", bm: int = 128,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bko), lambda ki, ni, mi: (mi, ki)),
-            pl.BlockSpec((bm, bko // MICRO), lambda ki, ni, mi: (mi, ki)),
+            pl.BlockSpec((1, bko // MICRO, bm),
+                         lambda ki, ni, mi: (mi, ki, 0)),
             pl.BlockSpec((bm, bn), lambda ki, ni, mi: (mi, ni)),
         ],
         out_specs=pl.BlockSpec((bko, bn), lambda ki, ni, mi: (ki, ni)),
         out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bko, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(qx, sexp, qg)
+    )(qx, sexp_to_tiles(sexp, bm), qg)

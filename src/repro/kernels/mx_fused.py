@@ -5,7 +5,8 @@ Fig. 3b): the activation (forward) or gradient (backward-dx) enters in
 bf16/f32 and leaves as a finished GEMM accumulation — the quantizer
 never round-trips through HBM.  Per (bm, bk) LHS tile the kernel
 
-  1. groups 32-wide micro-groups, takes amaxes,
+  1. groups 32-wide micro-groups (on the sublane axis of the
+     transposed tile — kernels/mx_tile.py), takes amaxes,
   2. derives the E8M0 level-2 exponents against the (precomputed)
      level-1 global scale,
   3. performs the saturating FP8 cast,
@@ -22,7 +23,7 @@ indexed (i, kk) only, so each is (re)written identically once per
 N-block — dead writes the Mosaic pipeliner keeps in VMEM.
 
 VMEM working set at the default (128, 128, 512) blocks:
-  bm·bk·4 (x) + bk·bn (qw) + bm·bn·4 (acc) + bm·bk (q) + bm·bk/32 (se)
+  bm·bk·4 (x) + bk·bn (qw) + bm·bn·4 (acc) + bm·bk (q) + bm·bk/8 (se)
 ≈ 0.45 MiB ≪ 16 MiB, leaving headroom for double buffering.
 
 Operand contract (see docs/kernel-contract.md)
@@ -33,6 +34,8 @@ Operand contract (see docs/kernel-contract.md)
   qw        (K, N) fp8      — per-tensor-quantized RHS *payload*; its
                               f32 scale s_w stays with the caller
   returns   acc (M, N) f32 UNSCALED, q (M, K) fp8, sexp (M, K//32) int8
+            (the pallas_call writes sexp in the (M/bm, K/32, bm) int32
+            tile layout of kernels/mx_tile.py; the wrapper converts back)
 
 Two-level scale convention: the effective scale of LHS micro-group g is
 ``s_global · 2^sexp[g]`` with ``2^sexp ∈ (0, 1]``; the kernel applies
@@ -54,11 +57,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.jaxapi import pallas_tpu_compiler_params
 from repro.core.formats import E4M3_MAX, E5M2_MAX
 
-MICRO = 32
-_TINY = 1e-30
+from .mx_tile import (MICRO, TILE_DTYPE, dot_t, quant_tile,
+                      scaled_operand_t, sexp_from_tiles, untranspose)
 
 
 def _fused_quant_gemm_kernel(x_ref, s_ref, qw_ref, o_ref, q_ref, se_ref,
@@ -71,25 +73,12 @@ def _fused_quant_gemm_kernel(x_ref, s_ref, qw_ref, o_ref, q_ref, se_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
-    bm, bk = x.shape
-    s = jnp.maximum(s_ref[0, 0], _TINY)
-    xg = x.reshape(bm, bk // MICRO, MICRO)
-    amax = jnp.max(jnp.abs(xg), axis=-1)                  # (bm, bk/32)
-    # E8M0 encode (identical guards to formats.e8m0_encode / mx_quant.py)
-    e = jnp.ceil(jnp.log2(jnp.maximum(amax / fp8_max / s,
-                                      2.0 ** -149)) - 1e-6)
-    e = jnp.clip(e, -127, 127)
-    se_ref[...] = e.astype(jnp.int8)
-    denom = jnp.exp2(e) * s
-    safe = jnp.where(denom > 0, denom, 1.0)[..., None]
-    q = jnp.where(denom[..., None] > 0, xg / safe, 0.0)
-    q = jnp.clip(q, -fp8_max, fp8_max).astype(q_dtype)    # saturating cast
-    q_ref[...] = q.reshape(bm, bk)
+    e, q = quant_tile(x, s_ref[0, 0], fp8_max=fp8_max, q_dtype=q_dtype)
+    se_ref[0] = e.astype(TILE_DTYPE)                      # (bk/32, bm)
+    q_ref[...] = untranspose(q)
     # operand path: quantized values × 2^e (exponent-only; exact in bf16)
-    ss = jnp.exp2(e).astype(jnp.bfloat16)
-    xop = (q.astype(jnp.bfloat16) * ss[..., None]).reshape(bm, bk)
     w = qw_ref[...].astype(jnp.bfloat16)                  # (bk, bn)
-    acc_ref[...] += jnp.dot(xop, w, preferred_element_type=jnp.float32)
+    acc_ref[...] += dot_t(scaled_operand_t(q, e), w)
 
     @pl.when(kk == n_k - 1)
     def _done():
@@ -131,16 +120,16 @@ def fused_quant_gemm_pallas(x, s_global, qw, *, fmt: str = "e4m3",
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bm, bk // MICRO), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((1, bk // MICRO, bm), lambda i, j, kk: (i, kk, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),
             jax.ShapeDtypeStruct((m, k), q_dtype),
-            jax.ShapeDtypeStruct((m, k // MICRO), jnp.int8),
+            jax.ShapeDtypeStruct((m // bm, k // MICRO, bm), TILE_DTYPE),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, s_global.reshape(1, 1), qw)
-    return acc, q, sexp
+    return acc, q, sexp_from_tiles(sexp)

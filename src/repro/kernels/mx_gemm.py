@@ -1,14 +1,16 @@
 """Pallas TPU kernel: two-level microscaled FP8 GEMM (paper Fig 3b,
-TPU-native — DESIGN.md §2).
+TPU-native).
 
 y[m, n] = Σ_k ( Qx[m, k] · 2^sexp[m, k/32] ) · Qw[k, n]
 
 The grid is (M/bm, N/bn, K/bk), K innermost ("arbitrary"); the f32
 accumulator lives in VMEM scratch.  Per K-block the E8M0 subscale is an
 exponent-only multiply applied to the *operand tile* on the VPU —
-O(bm·bk) cheap work — and the MXU dot runs on the rescaled bf16 tile.
-The single f32 epilogue multiply (s_x·s_w) happens OUTSIDE the kernel in
-ops.py (the paper's "dequant in the epilogue on CUDA cores").
+O(bm·bk) cheap work — and the MXU dot runs on the rescaled bf16 tile
+(transposed, micro-groups on the sublane axis; the exponents arrive in
+the (M/bm, K/32, bm) tile layout of kernels/mx_tile.py).  The single
+f32 epilogue multiply (s_x·s_w) happens OUTSIDE the kernel in the
+dispatch layer (the paper's "dequant in the epilogue on CUDA cores").
 
 Contrast with group_gemm.py (COAT baseline): there an O(bm·bn) f32
 multiply-accumulate of the partial-sum tile runs per K-block inside the
@@ -16,8 +18,8 @@ loop — the overhead MOSS eliminates.
 
 Block shapes default to (128, 128, 512): MXU-aligned (multiples of 128)
 and a VMEM working set of
-  bm·bk (fp8) + bk·bn (fp8) + bm·bn·4 (f32 acc) + bm·bk/32 (int8)
-= 64K + 64K + 64K·4 + 2K ≈ 0.4 MiB ≪ 16 MiB VMEM, leaving room for
+  bm·bk (fp8) + bk·bn (fp8) + bm·bn·4 (f32 acc) + bm·bk/8 (int32 se)
+= 64K + 64K + 64K·4 + 8K ≈ 0.4 MiB ≪ 16 MiB VMEM, leaving room for
 double buffering of the HBM→VMEM pipeline.
 """
 
@@ -30,9 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.jaxapi import pallas_tpu_compiler_params
-
-MICRO = 32
+from .mx_tile import MICRO, dot_t, scaled_operand_t, sexp_to_tiles
 
 
 def _mx_gemm_kernel(qx_ref, se_ref, qw_ref, o_ref, acc_ref, *, n_k: int):
@@ -42,14 +42,11 @@ def _mx_gemm_kernel(qx_ref, se_ref, qw_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = qx_ref[...].astype(jnp.bfloat16)                  # (bm, bk)
-    bm, bk = x.shape
     # E8M0 level-2 subscale: exponent-only operand rescale (exact in bf16)
-    ss = jnp.exp2(se_ref[...].astype(jnp.float32)).astype(jnp.bfloat16)
-    x = (x.reshape(bm, bk // MICRO, MICRO) * ss[:, :, None]
-         ).reshape(bm, bk)
+    xt = qx_ref[...].astype(jnp.float32).T                # (bk, bm)
+    x_op = scaled_operand_t(xt, se_ref[0])
     w = qw_ref[...].astype(jnp.bfloat16)                  # (bk, bn)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    acc_ref[...] += dot_t(x_op, w)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -76,13 +73,13 @@ def mx_gemm_pallas(qx, sexp, qw, *, bm: int = 128, bn: int = 128,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bm, bk // MICRO), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((1, bk // MICRO, bm), lambda i, j, kk: (i, kk, 0)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(qx, sexp, qw)
+    )(qx, sexp_to_tiles(sexp, bm), qw)

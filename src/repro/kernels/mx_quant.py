@@ -11,6 +11,10 @@ argument applied to the activation path).
 The global scale s = max_g(amax_g)/FP8_MAX needs a full reduction, so it
 is computed OUTSIDE (one fused jnp.max) and passed in as a (1, 1) f32
 operand.
+
+Exponents leave the kernel in the (M/bm, K/32, bm) tile layout of
+kernels/mx_tile.py (micro-groups on the sublane axis); the wrapper
+returns them as (M, K/32).
 """
 
 from __future__ import annotations
@@ -20,30 +24,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.jaxapi import pallas_tpu_compiler_params
 from repro.core.formats import E4M3_MAX, E5M2_MAX
 
-MICRO = 32
-_TINY = 1e-30
+from .mx_tile import (MICRO, TILE_DTYPE, quant_tile, sexp_from_tiles,
+                      untranspose)
 
 
 def _mx_quant_kernel(x_ref, s_ref, q_ref, se_ref, *, fp8_max: float,
                      out_dtype):
     x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
-    bm, bk = x.shape
-    s = jnp.maximum(s_ref[0, 0], _TINY)
-    xg = x.reshape(bm, bk // MICRO, MICRO)
-    amax = jnp.max(jnp.abs(xg), axis=-1)                  # (bm, bk/32)
-    s_g = amax / fp8_max
-    e = jnp.ceil(jnp.log2(jnp.maximum(s_g / s, 2.0 ** -149)) - 1e-6)
-    e = jnp.clip(e, -127, 127)
-    se_ref[...] = e.astype(jnp.int8)
-    denom = jnp.exp2(e) * s
-    safe = jnp.where(denom > 0, denom, 1.0)[..., None]
-    q = jnp.where(denom[..., None] > 0, xg / safe, 0.0)
-    q = jnp.clip(q, -fp8_max, fp8_max)
-    q_ref[...] = q.reshape(bm, bk).astype(out_dtype)
+    e, q = quant_tile(x, s_ref[0, 0], fp8_max=fp8_max, q_dtype=out_dtype)
+    se_ref[0] = e.astype(TILE_DTYPE)                      # (bk/32, bm)
+    q_ref[...] = untranspose(q)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "bm", "bk",
@@ -59,7 +53,7 @@ def mx_quant_pallas(x, s_global, *, fmt: str = "e4m3", bm: int = 256,
     fp8_max = E4M3_MAX if fmt == "e4m3" else E5M2_MAX
     out_dtype = jnp.float8_e4m3fn if fmt == "e4m3" else jnp.float8_e5m2
     grid = (m // bm, k // bk)
-    return pl.pallas_call(
+    q, sexp = pl.pallas_call(
         functools.partial(_mx_quant_kernel, fp8_max=fp8_max,
                           out_dtype=out_dtype),
         grid=grid,
@@ -69,13 +63,14 @@ def mx_quant_pallas(x, s_global, *, fmt: str = "e4m3", bm: int = 256,
         ],
         out_specs=[
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bk // MICRO), lambda i, j: (i, j)),
+            pl.BlockSpec((1, bk // MICRO, bm), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k), out_dtype),
-            jax.ShapeDtypeStruct((m, k // MICRO), jnp.int8),
+            jax.ShapeDtypeStruct((m // bm, k // MICRO, bm), TILE_DTYPE),
         ],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x, s_global.reshape(1, 1))
+    return q, sexp_from_tiles(sexp)

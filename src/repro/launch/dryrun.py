@@ -21,7 +21,6 @@ import traceback
 
 import jax
 
-from repro.compat import jaxapi
 from repro.core import runtime_flags
 from repro.configs.base import SHAPES, shape_applicable
 from repro.configs.registry import ASSIGNED, get_config
@@ -292,7 +291,7 @@ def segment_probes(cfg, shape, mesh, n_mb: int) -> dict:
         donate = (3,) if kind != "train" and args[3] is not None else ()
         compiled = jax.jit(probe_fn, in_shardings=shs,
                            donate_argnums=donate).lower(*args).compile()
-        cost = jaxapi.cost_analysis(compiled)
+        cost = dict(compiled.cost_analysis() or {})
         reps = seg.n * (n_mb if kind == "train" else 1)
         probes[seg.name] = {
             "flops": float(cost.get("flops", 0.0)),
@@ -391,7 +390,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
-            cost = jaxapi.cost_analysis(compiled)
+            cost = dict(compiled.cost_analysis() or {})
             mem = _memory_stats(compiled)
             coll = parse_collectives(compiled.as_text())
             probes = segment_probes(cfg, shape, mesh, n_mb)

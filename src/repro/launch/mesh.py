@@ -10,12 +10,20 @@ distributed/pipeline.py.
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.compat.jaxapi import make_mesh, mesh_from_devices
+
+def mesh_from_devices(devices, axis_names: Sequence[str]) -> Mesh:
+    """``Mesh`` over an explicit (nested) device array; every axis is
+    ``AxisType.Auto`` (GSPMD propagates the logical-axis sharding
+    rules of ``repro.distributed.sharding``)."""
+    return Mesh(np.asarray(devices), tuple(axis_names),
+                axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -24,7 +32,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return make_mesh(shape, axes)
+        # jax.make_mesh defaults to Explicit axes; this repo's sharding
+        # rules need Auto (GSPMD) axes
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "

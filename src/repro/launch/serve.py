@@ -14,6 +14,9 @@ einsum path) — see docs/serving.md.
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch phi3-mini-3.8b \
       --smoke --requests 16 --max-new 32
+  # published widths, depth cut to fit one chip:
+  PYTHONPATH=src python -m repro.launch.serve --arch phi3-mini-3.8b \
+      --full --layers 8 --requests 8 --slots 8 --max-new 32
 """
 
 from __future__ import annotations
@@ -27,6 +30,7 @@ import numpy as np
 
 from repro.configs.registry import get_config
 from repro.core.runtime_flags import serve_paged
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import init_tree
 from repro.models.transformer import init_caches, model_defs
 from repro.serving import Engine, Request, greedy_sample, prepare_weights
@@ -150,6 +154,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-mini-3.8b")
     ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="published widths (default: the smoke config)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -171,6 +179,7 @@ def main():
                          "Chrome-trace JSON at exit (same as "
                          "REPRO_TRACE=PATH)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = None
     if args.trace_out:
@@ -178,7 +187,7 @@ def main():
 
         tracer = get_tracer().enable(path=args.trace_out)
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    cfg = get_config(args.arch, smoke=args.smoke, layers=args.layers)
     defs = model_defs(cfg)
     params = init_tree(defs, jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)

@@ -9,6 +9,9 @@ Fault-tolerance contract (DESIGN.md §4):
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch olmo-7b --smoke \
       --steps 200 --batch 8 --seq 128 [--quant moss|bf16|per_tensor|...]
+  # published widths, depth cut to fit one chip:
+  PYTHONPATH=src python -m repro.launch.train --arch olmo-7b --full \
+      --layers 2 --steps 3 --batch 2 --seq 2048
 """
 
 from __future__ import annotations
@@ -23,8 +26,10 @@ import jax
 from repro.checkpoint import manager as ckpt
 from repro.configs.registry import get_config
 from repro.core.formats import QuantConfig
+from repro.core.introspect import count_pallas_calls
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.obs.trace import span, trace_enabled
 from repro.train.steps import TrainHParams, init_train_state, make_train_step
@@ -52,15 +57,25 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
           lr: float = 3e-4, warmup: int = 20, ckpt_dir: str | None = None,
           ckpt_every: int = 50, seed: int = 0, log_every: int = 10,
           mesh=None, microbatches: int = 1, interval: int = 500,
-          grad_comm_fp8: bool = False, log=print):
-    cfg = get_config(arch, smoke=smoke).replace(
+          grad_comm_fp8: bool = False, layers: int | None = None,
+          log=print):
+    cfg = get_config(arch, smoke=smoke, layers=layers).replace(
         quant=quant_from_name(quant, interval, grad_comm_fp8))
     hp = TrainHParams(peak_lr=lr, warmup_steps=warmup, total_steps=steps,
                       microbatches=microbatches)
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=seq,
                                   global_batch=batch, seed=seed))
 
-    state = init_train_state(cfg, hp, jax.random.PRNGKey(seed))
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        state = init_train_state(cfg, hp, key)
+    else:
+        # born sharded by the logical-axis rules: a replicated full-width
+        # state would not fit one device
+        from repro.launch.specs import state_shardings
+
+        state = jax.jit(lambda k: init_train_state(cfg, hp, k),
+                        out_shardings=state_shardings(cfg, mesh))(key)
     start_step = 0
     if ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
         state, start_step = ckpt.restore(ckpt_dir, state)
@@ -84,6 +99,14 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
                     # embeddings)
                     b = dict(b)
                     b["embeds"] = _stub_embeds(cfg, b["tokens"])
+            if step == start_step:
+                # compile before the first step (the step call then
+                # reuses it): on a TPU the kernel count shows that the
+                # GEMMs run the Pallas kernels, not the reference
+                compiled = jitted.lower(state, b).compile()
+                log(f"train step compiled: {count_pallas_calls(compiled)}"
+                    f" Pallas kernel calls")
+            t_step = time.time()
             with span("train.step", step=step):
                 state, metrics = jitted(state, b)
                 # spans wrap host wall time; blocking on the loss makes
@@ -93,11 +116,12 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
             tokens_done += batch * seq
             if (step + 1) % log_every == 0 or step + 1 == steps:
                 loss = float(metrics["loss"])
-                tps = tokens_done / (time.time() - t0)
+                now = time.time()
+                tps = tokens_done / (now - t0)
                 log(f"step {step+1:5d} loss {loss:.4f} "
                     f"lr {float(metrics['lr']):.2e} "
                     f"gnorm {float(metrics['grad_norm']):.2f} "
-                    f"tok/s {tps:,.0f}")
+                    f"step_s {now - t_step:.3f} tok/s {tps:,.0f}")
                 history.append((step + 1, loss))
             if ckpt_dir and ((step + 1) % ckpt_every == 0 or _PREEMPTED
                              or step + 1 == steps):
@@ -128,7 +152,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--full", dest="smoke", action="store_false")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="published widths (default: the smoke config)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -142,6 +169,7 @@ def main():
                     help="'host:<model>' to train over all local devices")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh and args.mesh.startswith("host"):
@@ -151,7 +179,8 @@ def main():
     train(args.arch, smoke=args.smoke, steps=args.steps,
           batch=args.batch, seq=args.seq, quant=args.quant, lr=args.lr,
           ckpt_dir=args.ckpt_dir, microbatches=args.microbatches,
-          grad_comm_fp8=args.grad_comm_fp8, mesh=mesh, seed=args.seed)
+          grad_comm_fp8=args.grad_comm_fp8, mesh=mesh, seed=args.seed,
+          layers=args.layers)
 
 
 if __name__ == "__main__":

@@ -38,11 +38,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat.jaxapi import shard_map
 from repro.core.formats import QuantConfig
 from repro.core.linear import QT, qlinear, qlinear_grouped
 from repro.core.runtime_flags import moe_expert_path
-from repro.distributed.sharding import _active_mesh
+from repro.distributed.sharding import active_mesh
 from .layers import PDef
 
 
@@ -202,7 +201,7 @@ def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
 
     from repro.core.actscale import REC
 
-    mesh = _active_mesh()
+    mesh = active_mesh()
     use_ep = (mesh is not None and mode not in ("decode", "verify")
               and "model" in mesh.axis_names)
     # calibration (REC.recording) forces the dense every-expert path:
@@ -245,7 +244,7 @@ def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
         wspec_down = P("model", None,
                        "data" if "data" in mesh.axis_names else None)
         sspec = P("model")
-        y = shard_map(
+        y = jax.shard_map(
             body, mesh=mesh,
             in_specs=(tok_spec, P(token_axes), tok_spec,
                       QT(wspec_up, sspec), QT(wspec_up, sspec),

@@ -202,6 +202,10 @@ def make_train_step(cfg, hp: TrainHParams, mesh=None):
         if qcfg.grad_comm_fp8 and mesh is not None:
             grads, new_res = compression.fp8_allreduce_grads(
                 grads, state.comm_residual, mesh)
+            # keep the error-feedback residual sharded like the params
+            # (as it was born — launch/specs.state_shardings)
+            new_res = jax.tree.map(jax.lax.with_sharding_constraint,
+                                   new_res, gspecs)
         else:
             new_res = state.comm_residual
 

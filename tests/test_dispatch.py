@@ -126,6 +126,28 @@ def test_backend_env_is_respected_per_call(monkeypatch):
                                rtol=1e-5, atol=1e-3)
 
 
+@pytest.mark.parametrize("entry", ["quantize", "fused", "matmul", "dw"])
+def test_kernel_backend_refuses_other_micro_group(entry):
+    """An ablation geometry (micro-group 16) has no kernel: under a
+    kernel backend the call raises instead of silently running the
+    reference; ``backend="ref"`` takes it."""
+    x, w = _problem(m=64, k=128, n=64)
+    wq = quant_per_tensor(w)
+    xq = quant_mx(x, 16)
+    calls = {
+        "quantize": lambda b: dispatch.mx_quantize(x, micro_group=16,
+                                                   backend=b),
+        "fused": lambda b: dispatch.fused_quant_matmul(
+            x, wq, micro_group=16, backend=b),
+        "matmul": lambda b: dispatch.mx_matmul(xq, wq, backend=b),
+        "dw": lambda b: dispatch.mx_matmul_dw(
+            xq, quant_per_tensor(x, "e5m2"), backend=b),
+    }
+    calls[entry]("ref")
+    with pytest.raises(ValueError, match="backend='ref'"):
+        calls[entry]("interpret")
+
+
 def test_unknown_backend_rejected(monkeypatch):
     from repro.core.runtime_flags import kernel_backend
 

@@ -16,7 +16,7 @@ def test_pipeline_matches_sequential():
     code = textwrap.dedent("""
         import numpy as np
         import jax, jax.numpy as jnp
-        from repro.compat.jaxapi import mesh_from_devices
+        from repro.launch.mesh import mesh_from_devices
         from repro.distributed.pipeline import pipeline_forward
 
         n_stages, n_micro, mb, d = 4, 8, 2, 16
