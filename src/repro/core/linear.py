@@ -101,6 +101,7 @@ def qmm(cfg: QuantConfig, x: jax.Array, w: jax.Array,
     return y
 
 
+@jax.named_scope("moss.scale")
 def _quantize_w(cfg: QuantConfig, w: jax.Array, w_scale: jax.Array):
     """Per-tensor weight quantization.  With automatic scaling the scale
     is the *predicted* one — no max-reduction over w in the HLO.
@@ -127,7 +128,9 @@ def _fwd_gemm(cfg: QuantConfig, x2d: jax.Array, wq: PerTensorQ):
     if cfg.mode == "moss":
         # fused quantize+GEMM: one pass over x, residual (q, sexp)
         # emitted by the same kernel (paper Fig. 3b steady state)
-        wq_p = PerTensorQ(q=_pad_axis(wq.q, 0, cfg.micro_group), s=wq.s)
+        with jax.named_scope("moss.scale"):
+            wq_p = PerTensorQ(q=_pad_axis(wq.q, 0, cfg.micro_group),
+                              s=wq.s)
         y, xq = dispatch.fused_quant_matmul(
             _pad_axis(x2d, -1, cfg.micro_group), wq_p,
             fmt=cfg.fwd_format, micro_group=cfg.micro_group,
@@ -194,7 +197,9 @@ def _qmm_bwd(cfg: QuantConfig, res, g):
     # per-tensor.  MOSS path: fused quantize+GEMM kernel, same operator
     # as the forward.
     if cfg.mode == "moss":
-        wqT = PerTensorQ(q=_pad_axis(wq.q.T, 0, cfg.micro_group), s=wq.s)
+        with jax.named_scope("moss.scale"):
+            wqT = PerTensorQ(q=_pad_axis(wq.q.T, 0, cfg.micro_group),
+                             s=wq.s)
         dx2d, _ = dispatch.fused_quant_matmul(
             _pad_axis(g2d, -1, cfg.micro_group), wqT, fmt=bfmt,
             micro_group=cfg.micro_group, out_dtype=jnp.float32)
@@ -216,7 +221,8 @@ def _qmm_bwd(cfg: QuantConfig, res, g):
     # level-1 scale to s_x so no second amax reduction appears
     # (kernels/mx_bwd.py).
     if cfg.mode == "moss":
-        g_pt = quant_per_tensor(g2d, bfmt)
+        with jax.named_scope("moss.scale"):
+            g_pt = quant_per_tensor(g2d, bfmt)
         dw = dispatch.mx_matmul_dw(xq, g_pt, fmt=cfg.fwd_format,
                                    out_dtype=jnp.float32, out_rows=k)
     elif cfg.mode == "per_group":

@@ -16,6 +16,7 @@ def _window(cfg):
     return None
 
 
+@jax.named_scope("attn_core")
 def chunked_attention(cfg, q, k, v, q_pos0: int = 0):
     """Flash-style causal attention.
 

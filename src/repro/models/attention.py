@@ -485,6 +485,7 @@ def _cache_write(cfg, cache: KVCache, k_new, v_new) -> KVCache:
     return KVCache(k, v, ks, vs, cache.idx + s_new)
 
 
+@jax.named_scope("attn")
 def attention(cfg, p, x, positions, qcfg: QuantConfig,
               cache: KVCache | None = None, mode: str = "train"):
     """Returns (out, new_cache).  Modes:

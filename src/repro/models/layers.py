@@ -183,6 +183,7 @@ def ffn_defs(cfg, d_ff: int | None = None):
     return defs
 
 
+@jax.named_scope("mlp")
 def apply_ffn(cfg, p, x, qcfg: QuantConfig):
     up = qlinear(x, p["w_up"], qcfg)
     if cfg.act == "swiglu":
@@ -213,6 +214,7 @@ def embed_defs(cfg):
     return defs
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg, p, tokens):
     emb = p["embedding"]
     emb = emb.w if isinstance(emb, QT) else emb

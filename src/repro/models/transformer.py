@@ -61,12 +61,15 @@ def _dense_unit(cfg, d_ff=None):
 
 
 def _dense_apply(cfg, qcfg, p, x, pos, cache, mode):
-    h, cache = attn_mod.attention(cfg, p["attn"],
-                                  apply_norm(cfg, p["ln1"], x), pos, qcfg,
-                                  cache, mode)
-    x = x + h
-    h = apply_ffn(cfg, p["ffn"], apply_norm(cfg, p["ln2"], x), qcfg)
-    return x + h, cache, jnp.zeros((), jnp.float32)
+    # each sub-block's profiler scope holds its pre-norm and residual add
+    with jax.named_scope("attn"):
+        h, cache = attn_mod.attention(cfg, p["attn"],
+                                      apply_norm(cfg, p["ln1"], x), pos,
+                                      qcfg, cache, mode)
+        x = x + h
+    with jax.named_scope("mlp"):
+        x = x + apply_ffn(cfg, p["ffn"], apply_norm(cfg, p["ln2"], x), qcfg)
+    return x, cache, jnp.zeros((), jnp.float32)
 
 
 def _moe_unit(cfg, use_mla: bool):
@@ -449,8 +452,9 @@ def forward(cfg, qcfg: QuantConfig, params, batch: dict,
             _qh_stash(hs)
             new_caches[seg.name] = c_seg
 
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = lm_head(cfg, params["embed"], x, qcfg)
+    with jax.named_scope("head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        logits = lm_head(cfg, params["embed"], x, qcfg)
     return logits, (new_caches if caches is not None else None), aux_total
 
 
@@ -467,6 +471,7 @@ def _first_idx(caches):
     return jnp.zeros((), jnp.int32)
 
 
+@jax.named_scope("loss")
 def ce_loss(cfg, logits, labels, mask=None):
     """Token cross-entropy in f32.
 

@@ -17,8 +17,11 @@ dict (never NaN/Inf — those serialize as invalid JSON; see the
 ``Scheduler.summary`` fix this PR rode in with), and
 ``to_prometheus()`` renders the standard text exposition format.
 
-Host-side and dependency-free by design: nothing here touches jax, so
-publishing metrics can never perturb a traced graph.
+Host-side by design: publishing metrics can never perturb a traced
+graph.  The one tie to JAX is ``count_compiles``: a listener on JAX's
+compile events that keeps ``jax_compiles_total`` (executables built,
+by an XLA compile or a load from the compilation cache) and
+``jax_compile_seconds_total``.
 """
 
 from __future__ import annotations
@@ -26,6 +29,8 @@ from __future__ import annotations
 import json
 import math
 import threading
+import time
+from collections import deque
 
 # Default latency buckets (seconds): 1ms .. 60s, roughly log-spaced.
 LATENCY_BUCKETS_S = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
@@ -253,3 +258,38 @@ _REGISTRY = Registry()
 def get_registry() -> Registry:
     """The process-wide default registry."""
     return _REGISTRY
+
+
+# -- JAX compiles ------------------------------------------------------
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_ENDS: deque = deque(maxlen=4096)    # time.monotonic() of each
+_COUNTING = False
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    _COMPILE_ENDS.append(time.monotonic())
+    reg = get_registry()
+    reg.counter("jax_compiles_total",
+                "executables JAX built (compiled or loaded from the "
+                "compilation cache)").inc()
+    reg.counter("jax_compile_seconds_total",
+                "seconds spent building them").inc(seconds)
+
+
+def count_compiles() -> None:
+    """Start counting JAX's compiles, once per process:
+    ``make_train_step`` and the serving engine call it."""
+    global _COUNTING
+    if not _COUNTING:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _COUNTING = True
+
+
+def compiles_between(t0: float, t1: float) -> int:
+    """Compiles counted that ended in [t0, t1] (``time.monotonic()``)."""
+    return sum(t0 <= t <= t1 for t in list(_COMPILE_ENDS))

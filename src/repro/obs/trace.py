@@ -12,11 +12,16 @@ Gates and cost:
 
   - off (the default): ``span()`` returns a shared no-op context
     manager — one dict lookup and zero allocations per call;
-  - ``REPRO_TRACE=path``: spans record into a RING BUFFER (default
-    65536 events, ``REPRO_TRACE_BUFFER`` overrides) so long serving
-    runs keep the last N events instead of growing without bound, and
-    the buffer is flushed to ``path`` at process exit (or explicitly
-    via ``get_tracer().save()`` / the CLIs' ``--trace-out``).
+  - ``REPRO_TRACE=path``: spans record into a RING BUFFER of 65536
+    events so long serving runs keep the last N events instead of
+    growing without bound, and the buffer is flushed to ``path`` at
+    process exit (or explicitly via ``get_tracer().save()`` / the
+    CLIs' ``--trace-out``).
+
+An enabled span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``: while the JAX profiler records
+(``jax.profiler.start_trace``) it lands in the same trace as the
+device's ops, on the profiler's clock (otherwise it costs a check).
 
 Durations measure wall time of the wrapped block.  JAX dispatch is
 asynchronous — a span around a step call measures dispatch unless the
@@ -34,6 +39,8 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
+
+import jax
 
 DEFAULT_BUFFER_EVENTS = 65536
 
@@ -99,7 +106,8 @@ class Tracer:
     def _span(self, name, args):
         t0 = time.perf_counter_ns()
         try:
-            yield None
+            with jax.profiler.TraceAnnotation(f"repro.{name}"):
+                yield None
         finally:
             t1 = time.perf_counter_ns()
             ev = {"name": name, "ph": "X", "ts": t0 // 1000,
@@ -143,21 +151,13 @@ _TRACER: Tracer | None = None
 _ATEXIT_REGISTERED = False
 
 
-def _buffer_capacity() -> int:
-    env = os.environ.get("REPRO_TRACE_BUFFER", "").strip()
-    try:
-        return int(env) if env else DEFAULT_BUFFER_EVENTS
-    except ValueError:
-        return DEFAULT_BUFFER_EVENTS
-
-
 def get_tracer() -> Tracer:
     """The process-wide tracer.  First call reads ``REPRO_TRACE``: a
     non-empty value enables tracing with that output path and
     registers an atexit flush."""
     global _TRACER, _ATEXIT_REGISTERED
     if _TRACER is None:
-        _TRACER = Tracer(capacity=_buffer_capacity())
+        _TRACER = Tracer()
         env = os.environ.get("REPRO_TRACE", "").strip()
         if env:
             _TRACER.enable(path=env)

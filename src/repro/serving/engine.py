@@ -103,7 +103,7 @@ from repro.train.steps import (
     serve_weight_scales,
 )
 
-from repro.obs.metrics import get_registry
+from repro.obs.metrics import count_compiles, get_registry
 from repro.obs.trace import instant, span
 
 from .paged_cache import (
@@ -194,6 +194,7 @@ class Engine:
                  spec_decode: bool | None = None,
                  draft: DraftSource | None = None,
                  spec_k: int = 4):
+        count_compiles()
         if cfg.input_mode != "tokens":
             raise ValueError(
                 f"serving engine drives token models; {cfg.name} has "

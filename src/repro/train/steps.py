@@ -22,6 +22,7 @@ from repro.core.linear import QT
 from repro.distributed import compression
 from repro.models.layers import quant_mask_tree, wrap_qt, wrap_qt_nojit
 from repro.models.transformer import ce_loss, forward, init_caches, model_defs
+from repro.obs.metrics import count_compiles
 from repro.optim.adamw import (
     AdamWConfig,
     adamw_update,
@@ -81,12 +82,14 @@ def init_scales(defs, params, qcfg: QuantConfig):
     return s0, t
 
 
+@jax.named_scope("moss.scale")
 def predicted_scales(s0, t, lr, qcfg: QuantConfig):
     def pred(s, ts):
         return s + lr * ts.astype(jnp.float32) / fp8_max(qcfg.fwd_format)
     return jax.tree.map(pred, s0, t)
 
 
+@jax.named_scope("moss.scale")
 def advance_scales(defs, s0, t, params, qcfg: QuantConfig):
     """One step forward; lax.cond refresh at the interval (the untaken
     branch reads no weight bytes — the paper's Table 1 saving)."""
@@ -134,6 +137,7 @@ def init_train_state(cfg, hp: TrainHParams, key, params=None):
 
 def make_train_step(cfg, hp: TrainHParams, mesh=None):
     """Builds the jittable train step for arch ``cfg``."""
+    count_compiles()
     defs = model_defs(cfg)
     mask = quant_mask_tree(defs)
     qcfg = cfg.quant
@@ -209,9 +213,11 @@ def make_train_step(cfg, hp: TrainHParams, mesh=None):
         else:
             new_res = state.comm_residual
 
-        grads, gnorm = clip_by_global_norm(grads, hp.grad_clip)
-        new_params, new_opt = adamw_update(hp.adamw, state.params, grads,
-                                           state.opt, state.step, lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, hp.grad_clip)
+            new_params, new_opt = adamw_update(hp.adamw, state.params,
+                                               grads, state.opt,
+                                               state.step, lr)
         if qcfg.quantized:
             new_s0, new_t = advance_scales(defs, state.scale_s0,
                                            state.scale_t, new_params, qcfg)

@@ -27,6 +27,8 @@ from repro.obs.metrics import (
     LATENCY_BUCKETS_S,
     RATE_BUCKETS,
     Registry,
+    compiles_between,
+    count_compiles,
     get_registry,
 )
 from repro.obs.quant_health import (
@@ -176,6 +178,51 @@ def test_trace_disabled_is_shared_noop():
     assert len(t) == 0
     t.instant("z")
     assert len(t) == 0
+
+
+def test_span_lands_in_the_profiler_trace(tmp_path):
+    """An enabled span is a host event ``repro.<name>`` of the JAX
+    profiler's trace, beside the device's ops and on their clock."""
+    import glob
+    import time
+
+    t = Tracer()
+    t.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("engine.step", rows=2):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(e.name, e.duration_ns)
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    spans = [d for n, d in events if n == "repro.engine.step"]
+    assert len(spans) == 1 and spans[0] >= 1e7
+    assert [e["name"] for e in t.events()] == ["engine.step"]
+
+
+def test_compile_counter_counts_each_new_executable():
+    import time
+
+    count_compiles()
+    count_compiles()                 # once per process: no second listener
+    reg = get_registry()
+    f = jax.jit(lambda a: a * 3.0 + 1.0)
+    x = jnp.arange(7.0)
+    n0 = reg.counter("jax_compiles_total").value()
+    s0 = reg.counter("jax_compile_seconds_total").value()
+    t0 = time.monotonic()
+    f(x).block_until_ready()
+    t1 = time.monotonic()
+    assert reg.counter("jax_compiles_total").value() == n0 + 1
+    assert reg.counter("jax_compile_seconds_total").value() > s0
+    assert compiles_between(t0, t1) == 1
+    f(x).block_until_ready()         # a cache hit builds nothing
+    assert reg.counter("jax_compiles_total").value() == n0 + 1
+    assert compiles_between(t1, time.monotonic()) == 0
 
 
 # ---------------------------------------------------------------------------
