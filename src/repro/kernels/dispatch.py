@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from repro.core import quant as Q
 from repro.core.quant import MxQ, PerGroupQ, PerTensorQ
 from repro.core.runtime_flags import KERNEL_BACKENDS, kernel_backend
+from repro.obs.metrics import get_registry
 from . import ref
 from .decode_attn import decode_attn_paged_pallas, decode_attn_pallas
 from .group_gemm import GROUP, group_gemm_pallas
@@ -150,6 +151,27 @@ def _per_shard(fn, *args, rows: tuple[int, ...],
                          out_specs=out_spec, check_vma=False)(*args)
 
 
+# VMEM for the fused kernel's resident row-block operand (Kp·bm bf16):
+# bm 256 where it fits FUSED_SCRATCH_BUDGET, else bm 128 up to
+# FUSED_RESIDENT_MAX; past that the kernel quantizes on every N block
+FUSED_SCRATCH_BUDGET = 8 * 2 ** 20
+FUSED_RESIDENT_MAX = 32 * 2 ** 20
+
+
+def _fused_blocks(mp: int, np_: int,
+                  kp: int) -> tuple[int, int, int, bool]:
+    """(bm, bn, bk, resident) for ``fused_quant_gemm_pallas`` on
+    128-padded M and N and a ``_k_pad``-ed K: the widest N block of
+    512/384/256/128 that divides N; bm 256 where M allows it and the row
+    block's operand fits ``FUSED_SCRATCH_BUDGET``, else 128; the operand
+    stays resident while it fits ``FUSED_RESIDENT_MAX`` (K up to 131072),
+    so the kernel's VMEM is bounded at any K."""
+    bn = next(b for b in (512, 384, 256, 128) if np_ % b == 0)
+    bm = 256 if mp % 256 == 0 and kp * 256 * 2 <= FUSED_SCRATCH_BUDGET \
+        else 128
+    return bm, bn, _k_block(kp), kp * bm * 2 <= FUSED_RESIDENT_MAX
+
+
 def _m_block(mp: int, min_mult: int = 8) -> int:
     for b in (256, 128, 64, 32, 16, 8):
         if b >= min_mult and mp % b == 0:
@@ -229,10 +251,21 @@ def fused_quant_matmul(x2d: jax.Array, wq: PerTensorQ,
         m, k = x2d.shape
         n = qw.shape[-1]
         mp, np_, kp = _ceil_to(m, 128), _ceil_to(n, 128), _k_pad(k)
+        bm, bn, bk, resident = _fused_blocks(mp, np_, kp)
+        # static counts of the traced call: the kernel quantizes each
+        # LHS tile once, on the first of its N blocks, when resident
+        tiles = mp // bm * (np_ // bn) * (kp // bk)
+        reg = get_registry()
+        reg.counter("mx_fused_lhs_tiles_quantized_total",
+                    "LHS tiles fused_quant_matmul quantizes, per traced "
+                    "call").inc(tiles // (np_ // bn) if resident else tiles)
+        reg.counter("mx_fused_gemm_tiles_total",
+                    "GEMM tiles fused_quant_matmul runs, per traced "
+                    "call").inc(tiles)
         acc, q, sexp = fused_quant_gemm_pallas(
             _pad_to(_pad_to(x2d, 0, mp), 1, kp), s,
             _pad_to(_pad_to(qw, 0, kp), 1, np_),
-            fmt=fmt, bm=128, bn=128, bk=_k_block(kp),
+            fmt=fmt, bm=bm, bn=bn, bk=bk, resident=resident,
             interpret=backend == "interpret")
         return ((acc[:m, :n] * (s * s_w)).astype(out_dtype), q[:m, :k],
                 sexp[:m, :k // MICRO])

@@ -124,6 +124,9 @@ def _paged_args(b, kv, g, pool, t, pages, dh):
 
 
 M, K, N = 4096, 4096, 11008                 # olmo-7b d_model / d_ff
+V = 50304                                   # olmo-7b vocab (the head)
+V_MAX = 256_000                             # the widest vocab (minitron-8b)
+K_RES = 131_072                             # widest K with a resident operand
 E, C, F = 16, 256, 6400                     # phi3.5-moe experts / d_ff
 CASES = {
     # olmo-7b training GEMMs: forward, dx, dW
@@ -133,6 +136,17 @@ CASES = {
                                    ((), F32)]),
     "mx_bwd": (_mx_bwd, [((M, K), F8), ((M, K // 32), I8), ((M, N), F5),
                          ((), F32)]),
+    # the head: an N of 393 x 128 blocks, and the widest resident K
+    "mx_fused_fwd_head": (_mx_fused_fwd, [((M, K), BF16), ((K, V), F8),
+                                          ((), F32)]),
+    "mx_fused_dx_head": (_mx_fused_dx, [((M, V), F32), ((V, K), F8),
+                                        ((), F32)]),
+    # the widest resident operand (32 MiB at bm 128), and a K past it,
+    # where the kernel quantizes on every N block in bounded VMEM
+    "mx_fused_dx_resident_max": (_mx_fused_dx, [((M, K_RES), F32),
+                                                ((K_RES, K), F8), ((), F32)]),
+    "mx_fused_dx_vocab_256k": (_mx_fused_dx, [((M, V_MAX), F32),
+                                              ((V_MAX, K), F8), ((), F32)]),
     "mx_quant": (_mx_quant, [((M, K), BF16)]),
     # serving decode GEMM on delayed-scale activations (8 rows)
     "mx_gemm": (_mx_gemm, [((8, K), F8), ((8, K // 32), I8), ((K, N), F8),
