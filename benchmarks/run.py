@@ -297,11 +297,9 @@ def bench_moe_grouped(B: int = 2, S: int = 128, iters: int = 5):
     from repro.models.layers import (init_tree, quant_mask_tree,
                                      wrap_qt_nojit)
 
-    # moe_decode_dense=False: without it the small-T single-device
-    # train path short-circuits to the masked dense-experts combine and
-    # the A/B would measure the dense path twice
-    cfg = get_config("phi3.5-moe-42b-a6.6b",
-                     smoke=True).replace(moe_decode_dense=False)
+    # the train path always dispatches, so both arms run the experts'
+    # GEMMs on the dispatched rows
+    cfg = get_config("phi3.5-moe-42b-a6.6b", smoke=True)
     qcfg = cfg.quant
     defs = moe.moe_defs(cfg)
     params = init_tree(defs, jax.random.PRNGKey(0))

@@ -32,6 +32,14 @@ class ModelConfig:
     window: int = 4096
     rope_theta: float = 10_000.0
     rope_pct: float = 1.0              # partial rotary (stablelm: 0.25)
+    # YaRN rope scaling (arXiv:2309.00071) of MLA's rope dims; factor 0
+    # is plain rope
+    yarn_factor: float = 0.0
+    yarn_orig_len: int = 4096          # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     qk_norm: bool = False
     logit_softcap: float = 0.0         # gemma-style final-logit softcap
 
@@ -40,10 +48,13 @@ class ModelConfig:
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0                 # routed experts the router scores
+    experts_held: int = 0              # experts [0, H) on this chip; 0: all
     n_shared: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.3
+    norm_topk: bool = True             # renormalise the top-k weights
+    seq_aux: bool = False              # balance loss per sequence, not batch
+    capacity_factor: float = 1.3       # dispatch outside training
     dense_ff: int = 0                  # width of non-MoE FFN layers
     first_dense: int = 0               # leading layers with dense FFN
 
@@ -81,7 +92,9 @@ class ModelConfig:
     # builds caches, so this has no effect on the training path; MLA's
     # absorbed latent cache ignores it.
     kv_cache_dtype: Literal["bf16", "fp8"] = "fp8"
-    moe_decode_dense: bool = True      # decode path: masked dense experts
+    # small-T prefill without a mesh: masked dense experts (decode and
+    # verify always take them; training never does)
+    moe_decode_dense: bool = True
     remat: bool = True
     scan_layers: bool = True
 
@@ -91,6 +104,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def is_attention_free(self) -> bool:

@@ -1,13 +1,18 @@
-"""deepseek-v2-lite-16b [moe]: 27L d=2048 16H MLA(kv_lora=512) MoE 64e
-top-6 + 2 shared, vocab 102400.  [arXiv:2405.04434; hf]
+"""deepseek-v2-lite-16b [mla_moe]: 27L d=2048 16H MLA(kv_lora=512) MoE,
+64 routed experts top-6 + 2 shared, vocab 102400.  [arXiv:2405.04434;
+hf:deepseek-ai/DeepSeek-V2-Lite config.json]
 
-Note: the assignment line reads "64e top-6 ... 2 shared+160 routed"; the
-published DeepSeek-V2-Lite config has 64 routed experts (160 routed is
-the full V2) — we follow the 64-routed/2-shared/top-6 reading and record
-the discrepancy here.
+Routing as published: softmax scores, top-6 weights not renormalised
+(``norm_topk_prob`` false, ``routed_scaling_factor`` 1), and the expert
+balance loss taken per sequence (``seq_aux``) with coefficient
+``AUX_COEF`` (α₁ of arXiv:2405.04434), which a training job passes as
+``TrainHParams.aux_coef``.  Rope: YaRN, factor 40 over an original
+4096 positions.
 """
 
 from .base import ModelConfig
+
+AUX_COEF = 0.001
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -22,6 +27,8 @@ CONFIG = ModelConfig(
     n_experts=64,
     n_shared=2,
     top_k=6,
+    norm_topk=False,
+    seq_aux=True,
     first_dense=1,
     dense_ff=10_944,
     kv_lora=512,
@@ -30,7 +37,14 @@ CONFIG = ModelConfig(
     v_head=128,
     act="swiglu",
     norm="rmsnorm",
+    norm_eps=1e-6,
     rope_theta=10_000.0,
+    yarn_factor=40.0,
+    yarn_orig_len=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
 )
 
 SMOKE_CONFIG = CONFIG.replace(

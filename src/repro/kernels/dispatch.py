@@ -324,6 +324,14 @@ def mx_matmul_dw(xq: MxQ, gq: PerTensorQ, fmt: str = "e4m3",
 # ---------------------------------------------------------------------------
 
 
+def _count_grouped_tiles(tiles: int) -> None:
+    """Static count of a traced grouped-kernel call's grid steps, empty
+    row blocks (whose dot the kernel skips) included."""
+    get_registry().counter(
+        "moe_grouped_gemm_tiles_total",
+        "grid tiles of the grouped MoE kernels, per traced call").inc(tiles)
+
+
 def moe_grouped_matmul(x2d: jax.Array, group_sizes: jax.Array,
                        qw_stack: jax.Array, w_scales: jax.Array, *,
                        capacity: int, fmt: str = "e4m3",
@@ -334,8 +342,10 @@ def moe_grouped_matmul(x2d: jax.Array, group_sizes: jax.Array,
 
     ``x2d`` is the flat sorted token buffer ``(E·C, K)`` — expert ``e``
     owns rows ``[e·C, e·C + group_sizes[e])``, the rest of each capacity
-    slot must be zero.  One global amax reduction covers the whole
-    buffer (vs E per-expert reductions on the vmapped path); per-expert
+    slot must be zero; with ``E·S`` group sizes, each of an expert's S
+    segments of C/S rows holds its own count (``moe_gmm_pallas``).  One
+    global amax reduction covers the whole buffer (vs E per-expert
+    reductions on the vmapped path); per-expert
     weight scales ``w_scales (E,)`` are applied row-wise in the
     epilogue.  Returns the finished GEMM ``(E·C, N)`` plus the fp8
     residual of the whole buffer (for the grouped custom-VJP)."""
@@ -353,12 +363,14 @@ def moe_grouped_matmul(x2d: jax.Array, group_sizes: jax.Array,
         _kernel_only(backend, micro_group == MICRO,
                      f"micro_group={micro_group}")
         np_, kp = _ceil_to(n, 128), _k_pad(k)
+        bm = _m_block(capacity * e // group_sizes.shape[0])
+        bk = _k_block(kp)
+        _count_grouped_tiles(t // bm * (np_ // 128) * (kp // bk))
         acc, q, sexp = moe_gmm_pallas(
             _pad_to(x2d, 1, kp), s,
             _pad_to(_pad_to(qw_stack, 1, kp), 2, np_),
             group_sizes.astype(jnp.int32), capacity=capacity, fmt=fmt,
-            bm=_m_block(capacity), bn=128, bk=_k_block(kp),
-            interpret=backend == "interpret")
+            bm=bm, bn=128, bk=bk, interpret=backend == "interpret")
         acc = acc[:, :n]
         xq = MxQ(q=q[:, :k], sexp=sexp[:, :k // MICRO], s=s)
     row_scale = s * jnp.repeat(w_scales.astype(jnp.float32), capacity)
@@ -376,7 +388,8 @@ def moe_grouped_matmul_dw(xq: MxQ, gq: PerTensorQ,
     quantized with ONE per-tensor scale.  Returns ``(E, K, N)`` (K
     sliced to ``out_rows`` when the residual carries micro padding).
     Per-expert rows are padded here to a micro-group multiple so the
-    along-token requantization never straddles an expert boundary."""
+    along-token requantization never straddles an expert boundary; an
+    expert's segments (``E·S`` group sizes) must each be one already."""
     backend = _resolve(backend)
     t, k = xq.q.shape
     assert t % capacity == 0
@@ -388,7 +401,10 @@ def moe_grouped_matmul_dw(xq: MxQ, gq: PerTensorQ,
         _kernel_only(backend, micro == MICRO, f"micro_group={micro}")
     # per-expert rows padded so the along-token requant groups (micro
     # tokens each) never straddle an expert boundary
+    segs = group_sizes.shape[0] // e
     cp = _ceil_to(capacity, micro if use_ref else MICRO)
+    assert segs == 1 or cp == capacity and (capacity // segs) % MICRO == 0, \
+        f"C/S={capacity}/{segs} must be a multiple of {MICRO}"
 
     def _pad_rows(a):
         if cp == capacity:
@@ -401,12 +417,13 @@ def moe_grouped_matmul_dw(xq: MxQ, gq: PerTensorQ,
         acc = ref.moe_dw_ref(qx, sexp, qg, cp, fmt, micro)
     else:
         np_, kp = _ceil_to(n, 128), _k_pad(k)
+        bm, bko = _m_block(cp // segs, min_mult=MICRO), _k_block(kp)
+        _count_grouped_tiles(e * (cp // bm) * (np_ // 128) * (kp // bko))
         acc = moe_dw_gemm_pallas(
             _pad_to(qx, 1, kp), _pad_to(sexp, 1, kp // MICRO),
             _pad_to(qg, 1, np_),
             group_sizes.astype(jnp.int32), capacity=cp, fmt=fmt,
-            bm=_m_block(cp, min_mult=MICRO), bn=128,
-            bko=_k_block(kp), interpret=backend == "interpret")
+            bm=bm, bn=128, bko=bko, interpret=backend == "interpret")
     acc = acc[:, :k if out_rows is None else out_rows, :n]
     return (acc * (xq.s * gq.s)).astype(out_dtype)
 

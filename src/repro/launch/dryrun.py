@@ -255,6 +255,8 @@ def segment_probes(cfg, shape, mesh, n_mb: int) -> dict:
                 def f(p_l, x):
                     y, _, aux = seg.apply(cfg, qcfg, wrap(p_l, sc), x,
                                           pos, None, "train")
+                    if isinstance(aux, dict):
+                        aux = aux["balance"]
                     return y.astype(jnp.float32).sum() + aux
 
                 if cfg.remat:   # match the scanned body: remat recompute

@@ -17,12 +17,14 @@ def _window(cfg):
 
 
 @jax.named_scope("attn_core")
-def chunked_attention(cfg, q, k, v, q_pos0: int = 0):
+def chunked_attention(cfg, q, k, v, q_pos0: int = 0,
+                      scale: float | None = None):
     """Flash-style causal attention.
 
     q: (B,S,H,Dh); k,v: (B,T,KV,Dh).  Outer lax.map over query chunks,
     inner lax.scan over KV chunks with an online softmax — peak score
-    memory is (B, Cq, H, Ck) instead of (B, S, H, T).
+    memory is (B, Cq, H, Ck) instead of (B, S, H, T).  Scores are scaled
+    by ``scale``, Dh^-0.5 by default.
     """
     b, s, h, dh = q.shape
     dv = v.shape[-1]                     # may differ from dh (MLA)
@@ -30,7 +32,7 @@ def chunked_attention(cfg, q, k, v, q_pos0: int = 0):
     kvh = k.shape[2]
     g = h // kvh
     window = _window(cfg)
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
 
     cq = min(cfg.attn_chunk, s)
     ck = min(cfg.attn_chunk, t)

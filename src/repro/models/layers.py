@@ -140,19 +140,64 @@ def rope_frequencies(dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, jnp.float32) / dim))
 
 
-def apply_rope(x, positions, theta: float = 1e4, pct: float = 1.0):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention magnitude 0.1·mscale·ln(factor) + 1 (1 unscaled)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_ramp(cfg, dim: int) -> tuple[int, int]:
+    """(low, high): the rope pairs below ``low`` keep their frequency,
+    those from ``high`` on are divided by the factor.  The pair turning
+    n times over the original context is c(n) = dim·ln(L/(2πn)) /
+    (2 ln θ); low = floor(c(β_fast)), high = ceil(c(β_slow))."""
+    def c(n):
+        return (dim * math.log(cfg.yarn_orig_len / (n * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    return (max(math.floor(c(cfg.yarn_beta_fast)), 0),
+            min(math.ceil(c(cfg.yarn_beta_slow)), dim - 1))
+
+
+def yarn_frequencies(cfg, dim: int):
+    """YaRN rope frequencies (arXiv:2309.00071, as DeepSeek-V2 applies
+    them): f·mask + (f/factor)·(1 - mask), with mask = 1 - clip((i -
+    low)/(high - low), 0, 1) over the pairs i."""
+    low, high = yarn_ramp(cfg, dim)
+    f = rope_frequencies(dim, cfg.rope_theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return f * (1.0 - ramp) + f / cfg.yarn_factor * ramp
+
+
+def rope_for(cfg, dim: int):
+    """(frequencies, cos/sin magnitude) of a rope of ``dim`` dims: the
+    plain ones (None, 1) unless the config scales them with YaRN."""
+    if not cfg.yarn_factor:
+        return None, 1.0
+    return yarn_frequencies(cfg, dim), (
+        yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+        / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+
+
+def apply_rope(x, positions, theta: float = 1e4, pct: float = 1.0,
+               freqs=None, mag: float = 1.0):
     """x: (..., S, H, Dh); positions: (..., S) int32.  Rotates the first
-    ``pct`` fraction of head dims (partial rotary for stablelm)."""
+    ``pct`` fraction of head dims (partial rotary for stablelm), at
+    ``freqs`` (plain rope at ``theta`` by default) with cos and sin
+    scaled by ``mag`` (``rope_for``)."""
     dh = x.shape[-1]
     rot = int(dh * pct)
     rot -= rot % 2
     if rot == 0:
         return x
     xr, xp = x[..., :rot], x[..., rot:]
-    freqs = rope_frequencies(rot, theta)                      # (rot/2,)
+    if freqs is None:
+        freqs = rope_frequencies(rot, theta)                  # (rot/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs    # (..., S, rot/2)
     cos = jnp.cos(ang)[..., None, :]                          # (..., S, 1, r/2)
     sin = jnp.sin(ang)[..., None, :]
+    if mag != 1.0:
+        cos, sin = cos * mag, sin * mag
     x1, x2 = jnp.split(xr.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)

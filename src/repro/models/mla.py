@@ -3,7 +3,8 @@
 Train/prefill materialize per-head K/V from the latent; decode uses the
 *absorbed* form so the cache is just (c_kv, k_rope) — (512+64) values
 per token shared across all heads.  Projections are MOSS-quantized; the
-tiny absorbed einsums stay bf16.
+tiny absorbed einsums stay bf16.  With ``cfg.yarn_factor`` the rope dims
+take YaRN's frequencies and the softmax YaRN's scale, in every mode.
 """
 
 from __future__ import annotations
@@ -17,7 +18,7 @@ from repro.core.formats import QuantConfig
 from repro.core.linear import QT, qlinear, dense_general
 from repro.core.runtime_flags import einsum as rf_einsum
 from repro.distributed.sharding import shard
-from .layers import PDef, apply_rope, rmsnorm
+from .layers import PDef, apply_rope, rmsnorm, rope_for, yarn_mscale
 from ._attn_core import chunked_attention
 
 
@@ -59,19 +60,31 @@ def cache_logical(cfg) -> MLACache:
                     k_rope=("batch", "kv_seq", None), idx=())
 
 
+def _rope(cfg, x, positions):
+    freqs, mag = rope_for(cfg, cfg.q_rope)
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs, mag=mag)
+
+
+def softmax_scale(cfg) -> float:
+    """(qk_nope + qk_rope)^-0.5, times YaRN's m² where it scales rope."""
+    m = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+         if cfg.yarn_factor and cfg.yarn_mscale_all_dim else 1.0)
+    return (cfg.q_nope + cfg.q_rope) ** -0.5 * m * m
+
+
 def _latent(cfg, p, x, positions, qcfg):
     c_kv = qlinear(x, p["w_dkv"], qcfg)                       # (B,S,512)
     c_kv = rmsnorm(c_kv, p["kv_norm"].w if isinstance(p["kv_norm"], QT)
                    else p["kv_norm"], cfg.norm_eps)
     k_r = qlinear(x, p["w_kr"], qcfg)[..., None, :]           # (B,S,1,64)
-    k_r = apply_rope(k_r, positions, cfg.rope_theta)[..., 0, :]
+    k_r = _rope(cfg, k_r, positions)[..., 0, :]
     return c_kv, k_r
 
 
 def _queries(cfg, p, x, positions, qcfg):
     q = dense_general(x, p["wq"], qcfg)                       # (B,S,H,192)
     q_n, q_r = q[..., :cfg.q_nope], q[..., cfg.q_nope:]
-    q_r = apply_rope(q_r, positions, cfg.rope_theta)
+    q_r = _rope(cfg, q_r, positions)
     return q_n, q_r
 
 
@@ -111,7 +124,7 @@ def mla_attention(cfg, p, x, positions, qcfg: QuantConfig,
                             out_dtype=jnp.float32)
                   + rf_einsum("bshr,btr->bsht", q_r, new_cache.k_rope,
                               out_dtype=jnp.float32))
-        scores *= (cfg.q_nope + cfg.q_rope) ** -0.5
+        scores *= softmax_scale(cfg)
         nv = jnp.broadcast_to(new_cache.idx.reshape(-1), (b,))
         valid = jnp.arange(t)[None, :] < jnp.minimum(nv, t)[:, None]
         scores = jnp.where(valid[:, None, None, :], scores, -1e30)
@@ -131,7 +144,7 @@ def mla_attention(cfg, p, x, positions, qcfg: QuantConfig,
         q = shard(q, "batch", None, "heads", None)
         k = shard(k, "batch", None, "heads", None)
         v = shard(v, "batch", None, "heads", None)
-        out = chunked_attention(cfg, q, k, v)
+        out = chunked_attention(cfg, q, k, v, scale=softmax_scale(cfg))
         new_cache = None
         if mode == "prefill":
             fresh = init_mla_cache(cfg, b, cache.c_kv.shape[1]

@@ -7,14 +7,33 @@ buffers to/from their experts (the torch-EP pattern, expressed
 jax-natively; the collectives land in the HLO where the roofline
 collective term can count them).
 
+Held experts: the router scores all ``n_experts`` and picks the top-k
+over all of them, but the layer holds the weights of experts [0, H)
+only (``cfg.experts_held``, one chip's share under expert parallelism).
+Only (token, slot) pairs routed to a held expert are dispatched, and
+the layer returns its own experts' part of the routed sum; what the
+absent experts would add is left out.
+
 Dispatch is sort-based (argsort by expert id + capacity truncation) —
-never materializes a (T, E, C) one-hot.  Per-device buffer is
-(E, C_local, d) with C_local = ceil(T_local·k·cf/E).
+never materializes a (T, E, C) one-hot — and moves rows by gathers
+alone, forward and backward: no scatter of rows, so no colliding
+writes whatever the routing.  Per-device buffer is
+(H, C_local, d).  Training is dropless: C_local = T_local (rounded up
+to 32), so every pair fits whatever the routing, and the grouped
+kernels skip each expert's empty row blocks; under EP the per-expert
+counts ride the all_to_all too, and the kernels skip the empty blocks
+of each source shard's segment, though the all_to_all itself moves all
+H·C_local rows.  Outside training C_local = ceil(T_local·k·cf/E) and
+overflow pairs are dropped.
 
 Decode path (T small): masked dense-experts combine — every expert runs
 on every token.  With batch≥experts·top_k the full expert weights are
 read anyway, so the memory roofline is identical and decode stays
-simple and shardable (DESIGN.md §3).
+simple and shardable (DESIGN.md §3).  Training never takes it.
+
+Profiler scopes: ``moe.router`` (router, top-k, balance loss),
+``moe.dispatch`` (sort, index maps, gathers, combine, the all-to-alls) and
+``moe.experts`` (the expert GEMMs).
 
 Router stays f32 and unquantized (tiny, accuracy-critical).  Expert
 GEMMs are MOSS-quantized with *per-expert* weight scales.
@@ -36,19 +55,21 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.formats import QuantConfig
 from repro.core.linear import QT, qlinear, qlinear_grouped
 from repro.core.runtime_flags import moe_expert_path
 from repro.distributed.sharding import active_mesh
+from repro.obs.metrics import get_registry
 from .layers import PDef
 
 
 def moe_defs(cfg):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held
     defs = {
-        "router": PDef((d, e), (None, None)),          # f32, not quantized
+        "router": PDef((d, cfg.n_experts), (None, None)),  # f32, unquantized
         "w_up": PDef((e, d, f), ("experts", "fsdp", "mlp"), quantized=True),
         "w_gate": PDef((e, d, f), ("experts", "fsdp", "mlp"), quantized=True),
         "w_down": PDef((e, f, d), ("experts", "mlp", "fsdp"), quantized=True),
@@ -93,13 +114,11 @@ def _experts_grouped(cfg, p, xs, sizes, qcfg):
     flattened to the sorted token buffer, 3 grouped GEMM launches + 1
     level-1 amax per GEMM instead of 3·E launches + E reductions.
 
-    ``sizes`` is the ragged per-expert valid-row count from dispatch;
-    None (the post-all_to_all EP case, where the counts live on the
-    source shards) means every capacity slot is treated as full —
-    dense-equivalent compute, still one launch per GEMM."""
+    ``sizes`` is the ragged valid-row count from dispatch: one per
+    expert, or after the EP all_to_all one per (expert, source shard)
+    segment of the expert's rows, so the kernels skip the empty row
+    blocks of every segment."""
     e, c, d = xs.shape
-    if sizes is None:
-        sizes = jnp.full((e,), c, jnp.int32)
     flat = xs.reshape(e * c, d)
     up = qlinear_grouped(flat, p["w_up"], sizes, c, qcfg)
     gate = qlinear_grouped(flat, p["w_gate"], sizes, c, qcfg)
@@ -136,68 +155,182 @@ def load_balance_loss(probs, ids, n_experts: int, top_k: int):
     return n_experts * jnp.sum(f * pmean)
 
 
+def balance_loss(cfg, probs, ids, batch: int):
+    """``load_balance_loss`` over the whole batch, or with
+    ``cfg.seq_aux`` over each of its ``batch`` sequences and averaged
+    (DeepSeek-V2's expert-level balance loss)."""
+    if not cfg.seq_aux:
+        return load_balance_loss(probs, ids, cfg.n_experts, cfg.top_k)
+    per_seq = jax.vmap(lambda p_, i_: load_balance_loss(
+        p_, i_, cfg.n_experts, cfg.top_k))
+    return jnp.mean(per_seq(probs.reshape(batch, -1, probs.shape[-1]),
+                            ids.reshape(batch, -1, ids.shape[-1])))
+
+
+@jax.custom_vjp
+def _to_slots(x, tok_of_slot, slot_of_pair):
+    """The experts' buffer rows: slot s holds token ``tok_of_slot[s]``,
+    or zeros where that index is past the last token.  Its transpose
+    sums each token's k slots (``slot_of_pair``, (T, k)) back, a gather
+    too, so neither direction scatters rows."""
+    return jnp.take(x, tok_of_slot, axis=0, mode="fill", fill_value=0)
+
+
+def _to_slots_fwd(x, tok_of_slot, slot_of_pair):
+    return (_to_slots(x, tok_of_slot, slot_of_pair),
+            (tok_of_slot, slot_of_pair))
+
+
+def _to_slots_bwd(res, dxs):
+    tok_of_slot, slot_of_pair = res
+    dx = sum(jnp.take(dxs, slot_of_pair[:, j], axis=0, mode="fill",
+                      fill_value=0).astype(jnp.float32)
+             for j in range(slot_of_pair.shape[1]))
+    return (dx.astype(dxs.dtype), _no_grad(tok_of_slot),
+            _no_grad(slot_of_pair))
+
+
+_to_slots.defvjp(_to_slots_fwd, _to_slots_bwd)
+
+
+@jax.custom_vjp
+def _from_slots(ys, w, slot_of_pair, tok_of_slot, pair_of_slot):
+    """y[t] = Σ_j w[t, j] · ys[slot_of_pair[t, j]] in f32, a slot index
+    past the last slot reading zeros.  Its transpose takes each slot's
+    cotangent from its token (``tok_of_slot``) and weight
+    (``pair_of_slot`` into the flat (T·k) weights) by gathers too."""
+    return sum(w[:, j:j + 1].astype(jnp.float32)
+               * jnp.take(ys, slot_of_pair[:, j], axis=0, mode="fill",
+                          fill_value=0).astype(jnp.float32)
+               for j in range(w.shape[1]))
+
+
+def _from_slots_fwd(ys, w, slot_of_pair, tok_of_slot, pair_of_slot):
+    return (_from_slots(ys, w, slot_of_pair, tok_of_slot, pair_of_slot),
+            (ys, w, slot_of_pair, tok_of_slot, pair_of_slot))
+
+
+def _from_slots_bwd(res, dy):
+    ys, w, slot_of_pair, tok_of_slot, pair_of_slot = res
+    w_slot = jnp.take(w.reshape(-1).astype(jnp.float32), pair_of_slot,
+                      mode="fill", fill_value=0)
+    # gathered in the rows' dtype: the layer casts y to it, so dy holds
+    # no more, and an f32 (H·C, d) copy would be twice the bytes
+    dys = (jnp.take(dy.astype(ys.dtype), tok_of_slot, axis=0, mode="fill",
+                    fill_value=0) * w_slot[:, None]).astype(ys.dtype)
+    dw = jnp.stack([
+        jnp.sum(jnp.take(ys, slot_of_pair[:, j], axis=0, mode="fill",
+                         fill_value=0).astype(jnp.float32) * dy, axis=-1)
+        for j in range(w.shape[1])], axis=1)
+    return (dys, dw.astype(w.dtype), _no_grad(slot_of_pair),
+            _no_grad(tok_of_slot), _no_grad(pair_of_slot))
+
+
+_from_slots.defvjp(_from_slots_fwd, _from_slots_bwd)
+
+
+def _no_grad(ints):
+    return np.zeros(ints.shape, jax.dtypes.float0)
+
+
 def _dispatch_combine_local(cfg, x_loc, ids_loc, w_loc, expert_fn,
                             capacity: int, model_axis: str | None):
     """Per-device dispatch -> (all_to_all) -> experts -> (all_to_all) ->
-    combine.  Runs inside shard_map (or standalone without a mesh)."""
+    combine.  Runs inside shard_map (or standalone without a mesh).
+    Returns (y, pairs routed to a held expert that did not fit).
+
+    The (token, slot) pairs are sorted by expert; from that order come
+    three index maps — the slot of each pair, the pair and the token of
+    each slot, an index past the end meaning none — and rows move
+    between tokens and slots by gathers alone, forward and backward."""
     t_loc, d = x_loc.shape
     k = ids_loc.shape[-1]
-    e = cfg.n_experts
+    e = cfg.n_held
 
-    flat_ids = ids_loc.reshape(-1)                       # (T·k,)
-    order = jnp.argsort(flat_ids, stable=True)
-    sorted_ids = flat_ids[order]
-    # position within expert group
-    group_start = jnp.searchsorted(sorted_ids, jnp.arange(e))
-    group_end = jnp.searchsorted(sorted_ids, jnp.arange(e), side="right")
-    # ragged per-expert valid-row counts — the grouped kernel's group
-    # sizes (capacity truncation applied, zero-size experts allowed)
-    sizes = jnp.minimum(group_end - group_start,
-                        capacity).astype(jnp.int32)
-    pos = jnp.arange(t_loc * k) - group_start[sorted_ids]
-    token_of = order // k
-    keep = pos < capacity
-    # scatter tokens into (E, C, d); dropped tokens overflow to a trash row
-    buf = jnp.zeros((e * capacity + 1, d), x_loc.dtype)
-    dest = jnp.where(keep, sorted_ids * capacity + pos, e * capacity)
-    buf = buf.at[dest].set(x_loc[token_of])
-    xs = buf[:-1].reshape(e, capacity, d)
+    with jax.named_scope("moe.dispatch"):
+        flat_ids = ids_loc.reshape(-1)                   # (T·k,)
+        order = jnp.argsort(flat_ids, stable=True)
+        sorted_ids = flat_ids[order]
+        # position within expert group
+        group_start = jnp.searchsorted(sorted_ids, jnp.arange(e))
+        group_end = jnp.searchsorted(sorted_ids, jnp.arange(e),
+                                     side="right")
+        # ragged per-expert valid-row counts — the grouped kernel's
+        # group sizes (capacity truncation applied, zero-size allowed)
+        sizes = jnp.minimum(group_end - group_start,
+                            capacity).astype(jnp.int32)
+        held = sorted_ids < e
+        pos = jnp.arange(t_loc * k) - group_start[jnp.minimum(sorted_ids,
+                                                              e - 1)]
+        keep = held & (pos < capacity)
+        dropped = jnp.sum(held & ~keep, dtype=jnp.int32)
+        dest = jnp.where(keep, sorted_ids * capacity + pos, e * capacity)
+        slot_of_pair = jnp.zeros((t_loc * k,), jnp.int32).at[order].set(
+            dest.astype(jnp.int32), unique_indices=True).reshape(t_loc, k)
+        c = jnp.arange(capacity)
+        at = jnp.minimum(group_start[:, None] + c, t_loc * k - 1)
+        pair_of_slot = jnp.where(c < sizes[:, None], order[at],
+                                 t_loc * k).reshape(-1)
+        tok_of_slot = pair_of_slot // k                  # T where none
+        xs = _to_slots(x_loc, tok_of_slot, slot_of_pair).reshape(
+            e, capacity, d)
+    get_registry().counter(
+        "moe_rows_allocated_total",
+        "rows of expert buffers dispatch allocates, per traced call"
+    ).inc(e * capacity)
 
     if model_axis is not None:
-        xs = jax.lax.all_to_all(xs, model_axis, split_axis=0,
-                                concat_axis=1, tiled=True)
-        ys = expert_fn(xs, None)   # counts live on the source shards
-        ys = jax.lax.all_to_all(ys, model_axis, split_axis=1,
-                                concat_axis=0, tiled=True)
+        with jax.named_scope("moe.dispatch"):
+            xs = jax.lax.all_to_all(xs, model_axis, split_axis=0,
+                                    concat_axis=1, tiled=True)
+            # this shard's experts' counts from every source shard,
+            # (source, expert) -> (expert, source): the segments of each
+            # expert's received rows
+            got = jax.lax.all_to_all(sizes, model_axis, split_axis=0,
+                                     concat_axis=0, tiled=True)
+            m = e // xs.shape[0]
+            seg_sizes = got.reshape(m, e // m).T.reshape(-1)
+        with jax.named_scope("moe.experts"):
+            ys = expert_fn(xs, seg_sizes)
+        with jax.named_scope("moe.dispatch"):
+            ys = jax.lax.all_to_all(ys, model_axis, split_axis=1,
+                                    concat_axis=0, tiled=True)
     else:
-        ys = expert_fn(xs, sizes)                        # (E, C, d)
+        with jax.named_scope("moe.experts"):
+            ys = expert_fn(xs, sizes)                    # (E, C, d)
 
-    ybuf = jnp.concatenate(
-        [ys.reshape(e * capacity, d),
-         jnp.zeros((1, d), ys.dtype)], axis=0)
-    gathered = ybuf[dest]                                # (T·k, d) sorted
-    # unsort back to (T, k, d)
-    unsort = jnp.argsort(order, stable=True)
-    per_slot = gathered[unsort].reshape(t_loc, k, d)
-    y = jnp.einsum("tkd,tk->td", per_slot.astype(jnp.float32),
-                   w_loc.astype(jnp.float32))
-    return y.astype(x_loc.dtype)
+    with jax.named_scope("moe.dispatch"):
+        y = _from_slots(ys.reshape(e * capacity, d), w_loc, slot_of_pair,
+                        tok_of_slot, pair_of_slot)
+    return y.astype(x_loc.dtype), dropped
 
 
-def _capacity(cfg, t_local: int) -> int:
+def _capacity(cfg, t_local: int, mode: str) -> int:
+    """Rows per held expert: every local token in training (dropless),
+    rounded up to 32 so that each source shard's segment of an expert's
+    rows under EP holds whole micro-groups for the grouped dW kernel;
+    else the capacity factor's share, rounded up to 8."""
+    if mode == "train":
+        return -(-t_local // 32) * 32
     c = int(t_local * cfg.top_k * cfg.capacity_factor / cfg.n_experts) + 1
-    return -(-c // 8) * 8                                # round up to 8
+    return -(-c // 8) * 8
 
 
 def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
-    """x: (B, S, d) -> (y, aux_loss)."""
+    """x: (B, S, d) -> (y, aux): y is the held experts' part of the
+    routed sum; aux holds the balance loss (``balance``), the (token,
+    slot) pairs routed to a held expert (``rows_routed``) and those of
+    them dropped for capacity (``slots_dropped``, 0 in training)."""
     b, s, d = x.shape
     x_flat = x.reshape(-1, d)
     t = x_flat.shape[0]
-    logits, probs = router_probs(cfg, p, x_flat)
-    top_w, top_ids = jax.lax.top_k(probs, cfg.top_k)     # (T,k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    aux = load_balance_loss(probs, top_ids, cfg.n_experts, cfg.top_k)
+    with jax.named_scope("moe.router"):
+        logits, probs = router_probs(cfg, p, x_flat)
+        top_w, top_ids = jax.lax.top_k(probs, cfg.top_k)  # (T,k)
+        if cfg.norm_topk:
+            top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        balance = balance_loss(cfg, probs, top_ids, b)
+        routed = jnp.sum(top_ids < cfg.n_held, dtype=jnp.int32)
 
     from repro.core.actscale import REC
 
@@ -212,9 +345,10 @@ def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
     # batch-composition-independent on the dense path, which the
     # token-for-token speculative exactness contract relies on.
     if mode in ("decode", "verify") or REC.recording or (
-            not use_ep and cfg.moe_decode_dense and t <= 4096):
-        y = _dense_moe(cfg, p, x_flat, probs, top_w, top_ids, qcfg)
-        return y.reshape(b, s, d), aux
+            mode == "prefill" and not use_ep and cfg.moe_decode_dense
+            and t <= 4096):
+        y = _dense_moe(cfg, p, x_flat, top_w, top_ids, qcfg)
+        return y.reshape(b, s, d), _aux(balance, routed, 0)
 
     if use_ep:
         token_axes = tuple(a for a in ("pod", "data", "model")
@@ -222,9 +356,8 @@ def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
         n_tok_shards = 1
         for a in token_axes:
             n_tok_shards *= mesh.shape[a]
-        m = mesh.shape["model"]
         t_loc = t // n_tok_shards
-        cap = _capacity(cfg, t_loc)
+        cap = _capacity(cfg, t_loc, mode)
 
         def body(x_loc, ids_loc, w_loc, w_up, w_gate, w_down):
             # FSDP all-gather of expert weights over the data axis
@@ -235,8 +368,9 @@ def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
                 w_up, w_gate, w_down = (QT(*w_up), QT(*w_gate), QT(*w_down))
             pl = {"w_up": w_up, "w_gate": w_gate, "w_down": w_down}
             fn = _expert_runner(cfg, pl, qcfg)
-            return _dispatch_combine_local(cfg, x_loc, ids_loc, w_loc, fn,
-                                           cap, "model")
+            y, dropped = _dispatch_combine_local(cfg, x_loc, ids_loc, w_loc,
+                                                 fn, cap, "model")
+            return y, dropped[None]
 
         tok_spec = P(token_axes, None)
         wspec_up = P("model", "data" if "data" in mesh.axis_names else None,
@@ -244,29 +378,45 @@ def moe_block(cfg, p, x, qcfg: QuantConfig, mode: str = "train"):
         wspec_down = P("model", None,
                        "data" if "data" in mesh.axis_names else None)
         sspec = P("model")
-        y = jax.shard_map(
+        y, dropped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(tok_spec, P(token_axes), tok_spec,
                       QT(wspec_up, sspec), QT(wspec_up, sspec),
                       QT(wspec_down, sspec)),
-            out_specs=tok_spec,
+            out_specs=(tok_spec, P(token_axes)),
             check_vma=False,
         )(x_flat, top_ids, top_w, p["w_up"], p["w_gate"], p["w_down"])
-        return y.reshape(b, s, d), aux
+        return y.reshape(b, s, d), _aux(balance, routed, dropped.sum())
 
-    # single-device fallback (smoke tests)
-    cap = _capacity(cfg, t)
+    # single device
+    cap = _capacity(cfg, t, mode)
     fn = _expert_runner(cfg, p, qcfg)
-    y = _dispatch_combine_local(cfg, x_flat, top_ids, top_w, fn, cap, None)
-    return y.reshape(b, s, d), aux
+    y, dropped = _dispatch_combine_local(cfg, x_flat, top_ids, top_w, fn,
+                                         cap, None)
+    return y.reshape(b, s, d), _aux(balance, routed, dropped)
 
 
-def _dense_moe(cfg, p, x_flat, probs, top_w, top_ids, qcfg):
-    """Masked dense-experts combine for small T (decode)."""
+def _aux(balance, routed, dropped) -> dict:
+    return {"balance": balance, "rows_routed": routed,
+            "slots_dropped": jnp.asarray(dropped, jnp.int32)}
+
+
+def no_aux(cfg) -> dict:
+    """The zero of a layer's aux: the balance loss, and for MoE models
+    the routing counts ``moe_block`` adds."""
+    if not cfg.n_experts:
+        return {"balance": jnp.zeros((), jnp.float32)}
+    return _aux(jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32), 0)
+
+
+def _dense_moe(cfg, p, x_flat, top_w, top_ids, qcfg):
+    """Masked dense-experts combine for small T (decode): every held
+    expert on every token."""
     t, d = x_flat.shape
+    e = cfg.n_held
     combine = jnp.zeros((t, cfg.n_experts), jnp.float32).at[
-        jnp.arange(t)[:, None], top_ids].set(top_w)
-    ys = _experts_vmapped(cfg, p, jnp.broadcast_to(x_flat, (cfg.n_experts, t, d)),
+        jnp.arange(t)[:, None], top_ids].set(top_w)[:, :e]
+    ys = _experts_vmapped(cfg, p, jnp.broadcast_to(x_flat, (e, t, d)),
                           qcfg)                           # (E,T,d)
     y = jnp.einsum("etd,te->td", ys.astype(jnp.float32), combine)
     return y.astype(x_flat.dtype)

@@ -42,6 +42,8 @@ class Segment(NamedTuple):
     n: int                       # repeats
     defs: dict                   # one unit's param defs (unstacked)
     apply: Callable              # (cfg,qcfg,p,x,pos,cache,mode)->(x,cache,aux)
+    # aux: a scalar balance loss (0 outside MoE blocks), or the dict
+    # ``moe.moe_block`` returns
     init_cache: Callable | None  # (cfg,batch,max_len)->one unit's cache
     cache_logical: Callable | None = None  # cfg -> logical axes pytree
 
@@ -87,14 +89,17 @@ def _moe_unit(cfg, use_mla: bool):
 def _moe_apply_factory(use_mla: bool):
     def apply(cfg, qcfg, p, x, pos, cache, mode):
         att = mla_mod.mla_attention if use_mla else attn_mod.attention
-        h, cache = att(cfg, p["attn"], apply_norm(cfg, p["ln1"], x), pos,
-                       qcfg, cache, mode)
-        x = x + h
-        hn = apply_norm(cfg, p["ln2"], x)
-        h, aux = moe_mod.moe_block(cfg, p["moe"], hn, qcfg, mode)
-        if cfg.n_shared > 0:
-            h = h + apply_ffn(cfg, p["shared"], hn, qcfg)
-        return x + h, cache, aux
+        with jax.named_scope("attn"):
+            h, cache = att(cfg, p["attn"], apply_norm(cfg, p["ln1"], x),
+                           pos, qcfg, cache, mode)
+            x = x + h
+        with jax.named_scope("mlp"):
+            hn = apply_norm(cfg, p["ln2"], x)
+            h, aux = moe_mod.moe_block(cfg, p["moe"], hn, qcfg, mode)
+            if cfg.n_shared > 0:
+                h = h + apply_ffn(cfg, p["shared"], hn, qcfg)
+            x = x + h
+        return x, cache, aux
     return apply
 
 
@@ -179,13 +184,15 @@ def build_segments(cfg) -> list[Segment]:
             }
 
             def dense_mla_apply(cfg_, qcfg, p, x, pos, cache, mode):
-                h, cache = mla_mod.mla_attention(
-                    cfg_, p["attn"], apply_norm(cfg_, p["ln1"], x), pos,
-                    qcfg, cache, mode)
-                x = x + h
-                h = apply_ffn(cfg_, p["ffn"], apply_norm(cfg_, p["ln2"], x),
-                              qcfg)
-                return x + h, cache, jnp.zeros((), jnp.float32)
+                with jax.named_scope("attn"):
+                    h, cache = mla_mod.mla_attention(
+                        cfg_, p["attn"], apply_norm(cfg_, p["ln1"], x), pos,
+                        qcfg, cache, mode)
+                    x = x + h
+                with jax.named_scope("mlp"):
+                    x = x + apply_ffn(cfg_, p["ffn"],
+                                      apply_norm(cfg_, p["ln2"], x), qcfg)
+                return x, cache, jnp.zeros((), jnp.float32)
 
             segs.append(Segment("dense0", cfg.first_dense, dense_cfg,
                                 dense_mla_apply, mla_mod.init_mla_cache,
@@ -378,9 +385,17 @@ def _qh_stash(tree) -> None:
         QH.stash_stacked(tree)
 
 
+def _add_aux(total: dict, part) -> dict:
+    """``total`` plus one block's aux (``Segment.apply``)."""
+    part = part if isinstance(part, dict) else {"balance": part}
+    return {k: v + part[k] if k in part else v for k, v in total.items()}
+
+
 def forward(cfg, qcfg: QuantConfig, params, batch: dict,
             caches=None, mode: str = "train"):
-    """Returns (logits, new_caches, aux_loss).
+    """Returns (logits, new_caches, aux): aux is ``moe.no_aux(cfg)``'s
+    dict summed over the layers — the balance loss (``balance``), and
+    in MoE models the routing counts of ``moe.moe_block``.
 
     batch: {"tokens": (B,S) int32} or {"embeds": (B,S,d)}; decode mode
     additionally relies on caches' idx for positions.
@@ -407,7 +422,7 @@ def forward(cfg, qcfg: QuantConfig, params, batch: dict,
         pe = sinusoidal_embedding(positions, cfg.d_model)
         x = x + (pe if positions.ndim > 1 else pe[None]).astype(x.dtype)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = moe_mod.no_aux(cfg)
     new_caches = {}
     for seg in build_segments(cfg):
         p_seg = params[seg.name]
@@ -419,7 +434,7 @@ def forward(cfg, qcfg: QuantConfig, params, batch: dict,
                 x_, aux_ = carry
                 x_, _, aux_l = seg.apply(cfg, qcfg, p_l, x_, positions,
                                          None, mode)
-                return (x_, aux_ + aux_l), None
+                return (x_, _add_aux(aux_, aux_l)), None
 
             if cfg.remat and mode == "train":
                 body = jax.checkpoint(body, prevent_cse=False)
@@ -444,7 +459,8 @@ def forward(cfg, qcfg: QuantConfig, params, batch: dict,
                 c_stack = jax.tree.map(
                     lambda c, u: jax.lax.dynamic_update_index_in_dim(
                         c, u.astype(c.dtype), li, 0), c_stack, c_new)
-                return (x_, aux_ + aux_l, c_stack, li + 1), _qh_drain()
+                return (x_, _add_aux(aux_, aux_l), c_stack, li + 1), \
+                    _qh_drain()
 
             (x, aux_total, c_seg, _), hs = jax.lax.scan(
                 body, (x, aux_total, c_seg, jnp.zeros((), jnp.int32)),

@@ -21,6 +21,7 @@ from repro.core.formats import QuantConfig, fp8_max, TINY
 from repro.core.linear import QT
 from repro.distributed import compression
 from repro.models.layers import quant_mask_tree, wrap_qt, wrap_qt_nojit
+from repro.models.moe import no_aux
 from repro.models.transformer import ce_loss, forward, init_caches, model_defs
 from repro.obs.metrics import count_compiles
 from repro.optim.adamw import (
@@ -160,7 +161,7 @@ def make_train_step(cfg, hp: TrainHParams, mesh=None):
                 qp = wrap_qt_nojit(params, mask)
             logits, _, aux = forward(cfg, qcfg, qp, mb, mode="train")
             loss = ce_loss(cfg, logits, mb["labels"], mb.get("mask"))
-            return loss + hp.aux_coef * aux, (loss, aux)
+            return loss + hp.aux_coef * aux["balance"], (loss, aux)
 
         n_mb = hp.microbatches
         if n_mb <= 1:
@@ -178,15 +179,18 @@ def make_train_step(cfg, hp: TrainHParams, mesh=None):
                 (_, (l, a)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(state.params, mb)
                 g_acc = jax.tree.map(jnp.add, g_acc, g)
-                return (g_acc, loss_acc + l, aux_acc + a), None
+                aux_acc = jax.tree.map(jnp.add, aux_acc, a)
+                return (g_acc, loss_acc + l, aux_acc), None
 
             g0 = jax.tree.map(lambda w: jnp.zeros(w.shape, jnp.float32),
                               state.params)
             (grads, loss, aux), _ = jax.lax.scan(
-                acc_step, (g0, jnp.zeros((), jnp.float32),
-                           jnp.zeros((), jnp.float32)), mbs)
+                acc_step, (g0, jnp.zeros((), jnp.float32), no_aux(cfg)),
+                mbs)
             grads = jax.tree.map(lambda g: g / n_mb, grads)
-            loss, aux = loss / n_mb, aux / n_mb
+            # the counts stay summed over the microbatches
+            loss = loss / n_mb
+            aux = dict(aux, balance=aux["balance"] / n_mb)
 
         if mesh is not None:
             # constrain gradients to the parameter sharding so GSPMD
@@ -224,7 +228,12 @@ def make_train_step(cfg, hp: TrainHParams, mesh=None):
         else:
             new_s0, new_t = state.scale_s0, state.scale_t
 
-        metrics = {"loss": loss, "aux": aux, "lr": lr, "grad_norm": gnorm}
+        metrics = {"loss": loss, "aux": aux["balance"], "lr": lr,
+                   "grad_norm": gnorm}
+        if cfg.n_experts:
+            # summed over the step's MoE layers (and microbatches)
+            metrics["moe_rows_routed"] = aux["rows_routed"]
+            metrics["moe_slots_dropped"] = aux["slots_dropped"]
         return TrainState(params=new_params, opt=new_opt, scale_s0=new_s0,
                           scale_t=new_t, comm_residual=new_res,
                           step=state.step + 1), metrics

@@ -40,9 +40,9 @@ def test_forward_shapes_and_finite(arch):
     logits, _, aux = forward(cfg, MOSS_CONFIG, qp, batch, mode="train")
     assert logits.shape == (B, S, cfg.vocab)
     assert bool(jnp.isfinite(logits).all())
-    assert bool(jnp.isfinite(aux))
+    assert bool(jnp.isfinite(aux["balance"]))
     if cfg.n_experts:
-        assert float(aux) > 0.0      # load-balance loss active
+        assert float(aux["balance"]) > 0.0   # load-balance loss active
 
 
 @pytest.mark.slow
@@ -110,3 +110,32 @@ def test_microbatched_step_matches_full(arch):
     _, m1 = jax.jit(make_train_step(cfg, hp1))(s1, batch)
     _, m2 = jax.jit(make_train_step(cfg, hp2))(s2, batch)
     assert abs(float(m1["loss"]) - float(m2["loss"])) < 0.02
+
+
+def test_yarn_rope_as_published():
+    """DeepSeek-V2-Lite's YaRN (factor 40 over 4096 positions, β 32/1,
+    mscale 0.707 on both): the ramp runs over rope pairs 10..23
+    (c(n) = 64 ln(4096/(2πn)) / (2 ln 10^4): 10.47 and 22.54), pairs
+    below keep θ^(-2i/64), pairs from 23 on divide it by 40, the
+    softmax scale is 192^-0.5 · m² with m = 0.1·0.707·ln 40 + 1, and
+    cos/sin keep magnitude 1.  Plain rope configs are untouched."""
+    import numpy as np
+
+    from repro.models.layers import (rope_for, rope_frequencies,
+                                     yarn_frequencies, yarn_ramp)
+    from repro.models.mla import softmax_scale
+
+    cfg = get_config("deepseek-v2-lite-16b")
+    assert yarn_ramp(cfg, 64) == (10, 23)
+    f = np.asarray(rope_frequencies(64, 1e4))
+    y = np.asarray(yarn_frequencies(cfg, 64))
+    np.testing.assert_allclose(y[:11], f[:11], rtol=1e-6)
+    np.testing.assert_allclose(y[23:], f[23:] / 40, rtol=1e-6)
+    i = 16                                   # (16 - 10) / 13 of the way
+    np.testing.assert_allclose(y[i], f[i] * (7 / 13) + f[i] / 40 * (6 / 13),
+                               rtol=1e-6)
+    assert abs(softmax_scale(cfg) / 192 ** -0.5 - 1.5896) < 1e-4
+    assert rope_for(cfg, 64)[1] == 1.0
+    plain = get_config("olmo-7b")
+    assert rope_for(plain, 128) == (None, 1.0)
+    assert softmax_scale(cfg.replace(yarn_factor=0.0)) == 192 ** -0.5

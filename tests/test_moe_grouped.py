@@ -90,6 +90,64 @@ def test_grouped_interpret_matches_ref_unaligned_capacity(monkeypatch):
         assert rel < 1e-5, rel
 
 
+# the expert-parallel layout: each expert's C = 64 rows arrive as S = 2
+# segments of 32, one from each source shard, each with its own count
+SEG_C, SEG_SIZES = 64, jnp.array([32, 3, 0, 0, 17, 32, 5, 0], jnp.int32)
+
+
+def _segment_buffer(key=5):
+    x = jax.random.normal(jax.random.PRNGKey(key), (E * SEG_C, D),
+                          jnp.float32)
+    row = jnp.arange(E * SEG_C)
+    valid = row % 32 < SEG_SIZES[row // 32]
+    return x, jnp.where(valid[:, None], x, 0.0)
+
+
+def test_grouped_segments_match_ref(monkeypatch):
+    """With one count per (expert, segment), as the expert-parallel
+    all_to_all delivers them, the interpreted kernels' fwd, dx and dW
+    equal the jnp reference, which computes every row."""
+    _, x = _segment_buffer()
+    w = _weights()
+
+    def run(backend):
+        monkeypatch.setenv("REPRO_KERNELS", backend)
+
+        def loss(x, w):
+            ws = jnp.max(jnp.abs(w), axis=(1, 2)) / 448.0
+            y = qmm_grouped(MOSS_CONFIG, SEG_C, x, w, ws, SEG_SIZES)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(x, w)
+
+    v_r, g_r = run("ref")
+    v_i, g_i = run("interpret")
+    assert abs(float(v_i) - float(v_r)) <= 1e-5 * abs(float(v_r))
+    for a, b in zip(g_i, g_r):
+        rel = float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-9))
+        assert rel < 1e-5, rel
+
+
+def test_grouped_kernel_skips_each_empty_segment(monkeypatch):
+    """The skip reads each row block's own segment count: rows of a
+    segment whose count is 0 are never multiplied (they come out 0 even
+    where the buffer holds data), and every other row is the
+    reference's."""
+    full, _ = _segment_buffer()
+    wq = jax.vmap(lambda wi: quant_per_tensor(wi, "e4m3"))(_weights())
+    y = {}
+    for backend in ("ref", "interpret"):
+        monkeypatch.setenv("REPRO_KERNELS", backend)
+        y[backend], _ = dispatch.moe_grouped_matmul(
+            full, SEG_SIZES, wq.q, wq.s, capacity=SEG_C,
+            out_dtype=jnp.float32)
+    empty = np.repeat(np.asarray(SEG_SIZES) == 0, 32)
+    got, want = np.asarray(y["interpret"]), np.asarray(y["ref"])
+    assert (got[empty] == 0).all() and (want[empty] != 0).any()
+    np.testing.assert_allclose(got[~empty], want[~empty], rtol=1e-5,
+                               atol=1e-5)
+
+
 def test_grouped_residual_matches_quant_mx(monkeypatch):
     """The grouped kernel's emitted residual must equal a standalone
     two-level quantization of the whole token buffer (one level-1
@@ -196,7 +254,7 @@ def _moe_block_ab(monkeypatch, quant):
         return moe.moe_block(cfg, qp, x, cfg.quant, mode="train")
 
     (y_g, aux_g), (y_v, aux_v) = block("grouped"), block("vmapped")
-    assert float(aux_g) == float(aux_v)
+    assert float(aux_g["balance"]) == float(aux_v["balance"])
     return y_g.astype(jnp.float32), y_v.astype(jnp.float32)
 
 
@@ -252,3 +310,185 @@ def test_moe_expert_path_env(monkeypatch):
     monkeypatch.setenv("REPRO_MOE_EXPERTS", "dense")
     with pytest.raises(ValueError):
         moe_expert_path()
+
+
+# ---------------------------------------------------------------------------
+# Routing: held experts, dropless training dispatch, top-k weights
+# ---------------------------------------------------------------------------
+
+
+def _moe_layer(arch="phi3.5-moe-42b-a6.6b", **kw):
+    from repro.configs.registry import get_config
+    from repro.models import moe
+    from repro.models.layers import init_tree, quant_mask_tree, wrap_qt_nojit
+
+    cfg = get_config(arch, smoke=True).replace(quant=BF16_CONFIG, **kw)
+    defs = moe.moe_defs(cfg)
+    params = init_tree(defs, jax.random.PRNGKey(0))
+    return cfg, params, wrap_qt_nojit(params, quant_mask_tree(defs))
+
+
+def _routed_by_hand(cfg, params, x, renorm: bool):
+    """Σ over each token's top-k held experts of w_k · FFN_k(x), in f32
+    from the layer's own weights: w_k the softmax probability, divided
+    by the top-k sum where ``renorm``."""
+    xf = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+    probs = jax.nn.softmax(xf @ params["router"], axis=-1)
+    w, ids = jax.lax.top_k(probs, cfg.top_k)
+    if renorm:
+        w = w / w.sum(-1, keepdims=True)
+    ys = jnp.stack([
+        (jax.nn.silu(xf @ params["w_gate"][e]) * (xf @ params["w_up"][e]))
+        @ params["w_down"][e] for e in range(cfg.n_held)])     # (H, T, d)
+    held = ids < cfg.n_held
+    picked = ys[jnp.minimum(ids, cfg.n_held - 1), jnp.arange(xf.shape[0])[:, None]]
+    return jnp.sum(jnp.where(held[..., None], w[..., None] * picked, 0.0),
+                   axis=1).reshape(x.shape)
+
+
+@pytest.mark.parametrize("norm_topk", [True, False])
+def test_top_k_weights_renormalised_only_where_configured(norm_topk,
+                                                          monkeypatch):
+    """``norm_topk=False`` (DeepSeek-V2's ``norm_topk_prob``) combines
+    the experts with their raw softmax probabilities; True divides by
+    their sum.  The two differ by O(1), so a wrong choice cannot pass
+    bf16's tolerance."""
+    from repro.models import moe
+
+    monkeypatch.setenv("REPRO_KERNELS", "ref")
+    cfg, params, qp = _moe_layer(top_k=4, norm_topk=norm_topk)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model),
+                          jnp.bfloat16)
+    y, _ = moe.moe_block(cfg, qp, x, cfg.quant, mode="train")
+    want = _routed_by_hand(cfg, params, x, renorm=norm_topk)
+    other = _routed_by_hand(cfg, params, x, renorm=not norm_topk)
+    err = float(jnp.linalg.norm(y.astype(jnp.float32) - want)
+                / jnp.linalg.norm(want))
+    gap = float(jnp.linalg.norm(other - want) / jnp.linalg.norm(want))
+    assert err < 0.02 < gap, (err, gap)
+
+
+def test_held_experts_compute_their_part_of_the_routed_sum(monkeypatch):
+    """A layer holding experts [0, 3) of 8 routes over all 8 and returns
+    only its own experts' part; the router keeps its published width."""
+    from repro.models import moe
+
+    monkeypatch.setenv("REPRO_KERNELS", "ref")
+    cfg, params, qp = _moe_layer(experts_held=3)
+    assert params["router"].shape[-1] == 8
+    assert params["w_up"].shape[0] == 3
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, cfg.d_model),
+                          jnp.bfloat16)
+    y, aux = moe.moe_block(cfg, qp, x, cfg.quant, mode="train")
+    want = _routed_by_hand(cfg, params, x, renorm=True)
+    err = float(jnp.linalg.norm(y.astype(jnp.float32) - want)
+                / jnp.linalg.norm(want))
+    assert err < 0.02, err
+    xf = x.reshape(-1, cfg.d_model).astype(jnp.float32)
+    _, ids = jax.lax.top_k(jax.nn.softmax(xf @ params["router"]), cfg.top_k)
+    assert int(aux["rows_routed"]) == int(jnp.sum(ids < 3))
+
+
+def test_train_dispatch_drops_nothing_when_one_expert_takes_all(
+        monkeypatch):
+    """A router that sends every token to the same experts overflows any
+    capacity factor: prefill's dispatch drops slots, training's drops
+    none and equals the combine computed by hand."""
+    from repro.models import moe
+
+    monkeypatch.setenv("REPRO_KERNELS", "ref")
+    cfg, params, _ = _moe_layer(moe_decode_dense=False)
+    params = dict(params, router=params["router"].at[:, :2].set(0.0)
+                  .at[0, :2].set(50.0))
+    from repro.models.layers import quant_mask_tree, wrap_qt_nojit
+
+    qp = wrap_qt_nojit(params, quant_mask_tree(moe.moe_defs(cfg)))
+    # every token's first feature large: experts 0 and 1 win everywhere
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, cfg.d_model),
+                          jnp.float32).at[..., 0].set(4.0).astype(jnp.bfloat16)
+    t = x.shape[0] * x.shape[1]
+    _, aux_p = moe.moe_block(cfg, qp, x, cfg.quant, mode="prefill")
+    y, aux = moe.moe_block(cfg, qp, x, cfg.quant, mode="train")
+    assert int(aux_p["slots_dropped"]) > 0
+    assert int(aux["slots_dropped"]) == 0
+    assert int(aux["rows_routed"]) == t * cfg.top_k
+    want = _routed_by_hand(cfg, params, x, renorm=True)
+    err = float(jnp.linalg.norm(y.astype(jnp.float32) - want)
+                / jnp.linalg.norm(want))
+    assert err < 0.02, err
+
+
+@pytest.mark.parametrize("capacity", [32, 4])
+def test_dispatch_and_combine_gradients_match_autodiff_by_hand(capacity):
+    """The gather-only dispatch and combine (custom transposes) against
+    autodiff of the same routing written by hand: each kept (token, slot)
+    pair adds w · s_e · x, with s_e an expert's own scale; at capacity 4
+    the pairs past an expert's first 4 (in token order) are dropped."""
+    import types
+
+    from repro.models import moe
+
+    t, d, k, held = 24, 8, 3, 4
+    cfg = types.SimpleNamespace(n_held=held)
+    kx, kw, ki = jax.random.split(jax.random.PRNGKey(6), 3)
+    x = jax.random.normal(kx, (t, d), jnp.float32)
+    w = jax.random.uniform(kw, (t, k), jnp.float32)
+    ids = jax.random.permutation(ki, jnp.tile(jnp.arange(6), (t, 1)),
+                                 axis=1, independent=True)[:, :k]
+    scale = jnp.arange(1.0, held + 1.0)
+
+    def program(x, w):
+        y, dropped = moe._dispatch_combine_local(
+            cfg, x, ids, w, lambda xs, sizes: xs * scale[:, None, None],
+            capacity, None)
+        return y, dropped
+
+    flat = np.asarray(ids).reshape(-1)
+    rank = np.array([np.sum(flat[:p] == flat[p]) for p in range(flat.size)])
+    kept = jnp.asarray(((flat < held) & (rank < capacity)).reshape(t, k))
+
+    def by_hand(x, w):
+        s_pair = scale[jnp.minimum(ids, held - 1)]
+        return jnp.sum(jnp.where(kept, w * s_pair, 0.0)[..., None]
+                       * x[:, None, :], axis=1)
+
+    (y, dropped), (y_h) = program(x, w), by_hand(x, w)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_h), rtol=1e-6,
+                               atol=1e-6)
+    assert int(dropped) == int(np.sum((flat < held) & (rank >= capacity)))
+    assert (int(dropped) > 0) == (capacity < t)
+    probe = jax.random.normal(jax.random.PRNGKey(7), (t, d), jnp.float32)
+    g_p = jax.grad(lambda x, w: jnp.sum(program(x, w)[0] * probe),
+                   argnums=(0, 1))(x, w)
+    g_h = jax.grad(lambda x, w: jnp.sum(by_hand(x, w) * probe),
+                   argnums=(0, 1))(x, w)
+    for a, b in zip(g_p, g_h):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_training_never_takes_the_dense_combine(monkeypatch):
+    """Training (and its eval forward) dispatches whatever the size; the
+    masked dense combine is left to decode, verify, calibration and
+    small prefills.  The step reports its routing counts."""
+    from repro.configs.registry import get_config
+    from repro.models import moe
+    from repro.train.steps import (TrainHParams, init_train_state,
+                                   make_train_step)
+
+    monkeypatch.setenv("REPRO_KERNELS", "ref")
+    calls = []
+    real = moe._dense_moe
+    monkeypatch.setattr(moe, "_dense_moe",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    cfg = get_config("deepseek-v2-lite-16b", smoke=True)
+    assert cfg.moe_decode_dense
+    hp = TrainHParams(peak_lr=1e-3, warmup_steps=0, total_steps=4)
+    state = init_train_state(cfg, hp, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab)
+    _, m = jax.jit(make_train_step(cfg, hp))(
+        state, {"tokens": tokens, "labels": tokens})
+    assert calls == []
+    assert int(m["moe_slots_dropped"]) == 0
+    moe_layers = cfg.n_layers - cfg.first_dense
+    assert int(m["moe_rows_routed"]) == moe_layers * 64 * cfg.top_k

@@ -21,7 +21,7 @@ from repro.core.formats import (
     PER_TENSOR_CONFIG,
 )
 from repro.models.layers import init_tree, quant_mask_tree, wrap_qt_nojit
-from repro.models.transformer import forward, model_defs
+from repro.models.transformer import forward, init_caches, model_defs
 from repro.train.steps import (
     make_decode_step,
     make_prefill_step,
@@ -37,8 +37,8 @@ ARCHS = ["phi3-mini-3.8b", "h2o-danube-3-4b", "rwkv6-3b",
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ARCHS)
 def test_decode_matches_teacher_forcing(arch):
-    # capacity_factor high so MoE archs drop no tokens in train mode
-    # (decode's dense-experts path is dropless by construction)
+    # capacity_factor high so MoE archs drop no tokens in prefill's
+    # dispatch (decode's dense-experts path is dropless by construction)
     cfg = get_config(arch, smoke=True).replace(quant=BF16_CONFIG,
                                                capacity_factor=8.0,
                                                kv_cache_dtype="bf16")
@@ -48,8 +48,13 @@ def test_decode_matches_teacher_forcing(arch):
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + EXTRA), 0,
                               cfg.vocab)
     qp = wrap_qt_nojit(params, quant_mask_tree(defs))
+    # MoE archs: teacher forcing through prefill's routing (the dense
+    # combine at this size), since training dispatches instead
+    moe_arch = bool(cfg.n_experts)
     full, _, _ = forward(cfg, cfg.quant, qp, {"tokens": toks},
-                         mode="train")
+                         init_caches(cfg, B, S + EXTRA) if moe_arch
+                         else None,
+                         mode="prefill" if moe_arch else "train")
     scale = float(jnp.abs(full).max()) + 1e-6
 
     pre = jax.jit(make_prefill_step(cfg, max_len=S + EXTRA))

@@ -507,9 +507,9 @@ def test_accept_rate_ema_steers_draft_len():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: PR 2 NOTE regression — small-T single-device MoE train
-# short-circuits to the dense decode combine unless moe_decode_dense
-# is explicitly disabled
+# Satellite: small-T single-device MoE routing — prefill short-circuits
+# to the dense decode combine unless moe_decode_dense is disabled;
+# training never does (it dispatches dropless)
 # ---------------------------------------------------------------------------
 
 
@@ -518,10 +518,11 @@ def test_accept_rate_ema_steers_draft_len():
 def test_moe_small_t_train_routes_dense_combine(dense_flag,
                                                 expect_dense,
                                                 monkeypatch):
-    """Pin the PR 2 routing decision: on a single device with small T
-    the TRAIN path short-circuits to the dense decode combine (one
-    gather-free einsum) unless ``moe_decode_dense=False`` — future
-    engine/scheduler changes must not silently flip it."""
+    """Pin the routing decision: on a single device with small T the
+    PREFILL path short-circuits to the dense decode combine (one
+    gather-free einsum) unless ``moe_decode_dense=False``, and the TRAIN
+    path takes the sort-based dispatch whatever the flag — future
+    engine/scheduler changes must not silently flip either."""
     from repro.models import moe as M
     from repro.models.layers import quant_mask_tree, wrap_qt_nojit
 
@@ -546,6 +547,7 @@ def test_moe_small_t_train_routes_dense_combine(dense_flag,
     monkeypatch.setattr(M, "_dispatch_combine_local", spy_dc)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model),
                           jnp.bfloat16)                 # T = 16 << 4096
+    M.moe_block(cfg, qp, x, cfg.quant, mode="prefill")
     M.moe_block(cfg, qp, x, cfg.quant, mode="train")
     assert seen == (["dense"] if expect_dense
-                    else ["dispatch"]), (dense_flag, seen)
+                    else ["dispatch"]) + ["dispatch"], (dense_flag, seen)

@@ -155,6 +155,13 @@ CASES = {
                            ((E, K, F), F8), ((E,), F32)]),
     "moe_gmm_dw": (_moe_gmm_dw, [((E * C, K), F8), ((E * C, K // 32), I8),
                                  ((E * C, F), F5), ((E,), I32), ((), F32)]),
+    # expert parallelism over 4 shards: 4 counted segments per expert
+    "moe_gmm_segments": (_moe_gmm, [((E * C, K), BF16), ((E * 4,), I32),
+                                    ((E, K, F), F8), ((E,), F32)]),
+    "moe_gmm_dw_segments": (_moe_gmm_dw, [((E * C, K), F8),
+                                          ((E * C, K // 32), I8),
+                                          ((E * C, F), F5), ((E * 4,), I32),
+                                          ((), F32)]),
     # one C block; and the single-block ceiling (C = MAX_SINGLE_BLOCK)
     "decode_contiguous": (_decode, _decode_args(8, 8, 4, 1024, 128)),
     "decode_contiguous_ceiling": (_decode, _decode_args(8, 8, 4, 2048, 128)),
